@@ -12,8 +12,6 @@ One module per paper table/figure:
 """
 from __future__ import annotations
 
-import traceback
-
 from benchmarks import (bench_dp, bench_elastic, bench_lcs, bench_mm,
                         bench_moe, bench_serve, bench_sort, bench_strassen)
 from benchmarks.common import flush_header
@@ -23,11 +21,7 @@ def main() -> None:
     flush_header()
     for mod in (bench_mm, bench_strassen, bench_lcs, bench_sort, bench_dp,
                 bench_moe, bench_elastic, bench_serve):
-        try:
-            mod.main()
-        except Exception:
-            print(f"{mod.__name__},ERROR,")
-            traceback.print_exc()
+        mod.main()
 
 
 if __name__ == "__main__":

@@ -25,9 +25,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 def choose_pivots(x: jax.Array, p: int, key: jax.Array,

@@ -19,9 +19,8 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 def stage_ranges(n_layers: int, n_stages: int) -> list[tuple[int, int]]:

@@ -124,10 +124,10 @@ def _q_block(c: int, cap: int = 128) -> int:
 
 def _paged_prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                           m_ref, l_ref, acc_ref, *, scale: float, bq: int,
-                          page: int, pps: int, window: int | None,
-                          logit_cap: float | None):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+                          hq: int, hkv: int, page: int, pps: int,
+                          window: int | None, logit_cap: float | None):
+    i = pl.program_id(0)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -135,24 +135,29 @@ def _paged_prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)             # (bq, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)    # (page, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    d = q_ref.shape[-1]
+    q = q_ref[...].astype(jnp.float32)           # (bq*Hq, d): row t*Hq + h
+    # every kv head of the page in one block; row p*Hkv + h of the
+    # flattened tile is position p of kv head h
+    k = k_ref[0].astype(jnp.float32).reshape(page * hkv, d)
+    v = v_ref[0].astype(jnp.float32).reshape(page * hkv, d)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if logit_cap is not None:
         s = jnp.tanh(s / logit_cap) * logit_cap
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     # q positions are GLOBAL (start + chunk offset): the chunk attends
     # causally over the slot's whole gathered context, so stale or
     # not-yet-written page contents (k_pos > q_pos) are masked here.
-    q_pos = start_ref[0] + i * bq + jax.lax.broadcasted_iota(
-        jnp.int32, (bq, page), 0)
-    k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (bq, page), 1)
-    mask = q_pos >= k_pos
+    q_pos = start_ref[0] + i * bq + row // hq
+    k_pos = j * page + col // hkv
+    # a query head scores only the columns of its own kv group
+    mask = ((row % hq) // (hq // hkv) == col % hkv) & (q_pos >= k_pos)
     if window is not None:
         mask &= (q_pos - k_pos) < window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                          # (bq, 1)
+    m_prev = m_ref[...]                          # (bq*Hq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
@@ -163,8 +168,8 @@ def _paged_prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == pps - 1)
     def _flush():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] /
+                      jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -175,53 +180,57 @@ def paged_flash_prefill_pallas(q: jax.Array, k_pages: jax.Array,
                                window: int | None = None,
                                logit_cap: float | None = None,
                                interpret: bool = False) -> jax.Array:
-    """Paged chunked prefill for ONE slot: q (Hq, C, D) at positions
+    """Paged chunked prefill for ONE slot: q (C, Hq, D) at positions
     [start, start+C) vs page pools (n_pages, page, Hkv, D) indexed by
     block_row (pages_per_seq,).
 
     The prefill sibling of ``paged_flash_decode_pallas``: block_row and
     start ride scalar prefetch so the K/V BlockSpec index_map routes grid
-    step (h, i, j) to physical page ``block_row[j]`` — one (page, D) PACO
-    leaf-tile DMA per step, never a gathered dense (max_seq, D) cache.
-    The grid (Hq, C/bq, pps) is the cut tree of the chunk's
-    queries x keys x head_dim cuboid with the page axis innermost, so the
-    online-softmax (m, l, acc) state stays in VMEM across key pages.
-    Causal masking is GLOBAL (q_pos = start + chunk offset), which also
-    masks stale/future page contents.  Returns (Hq, C, D).
+    step (i, j) to physical page ``block_row[j]`` — one whole
+    (page, Hkv, D) leaf-tile DMA per step, never a gathered dense
+    (max_seq, D) cache.  The TPU compiler tiles the last two block dims
+    by (8, 128), so the K/V block spans all Hkv heads (a size-1 head
+    block is refused); query heads fold into the q-block rows (as in the
+    latent kernel) and each query head masks out the other groups'
+    columns — Hkv-fold redundant MXU work per step, in exchange for one
+    dense (bq*Hq, page*Hkv) score tile.  The grid (C/bq, pps) keeps the
+    page axis innermost so the online-softmax (m, l, acc) state stays in
+    VMEM across key pages.  Causal masking is GLOBAL (q_pos = start +
+    chunk offset), which also masks stale/future page contents.
+    Returns (C, Hq, D).
     """
-    hq, c, d = q.shape
+    c, hq, d = q.shape
     _, page, hkv, _ = k_pages.shape
-    g = hq // hkv
     pps = block_row.shape[0]
-    bq = _q_block(c)
-    grid = (hq, c // bq, pps)
+    bq = _q_block(c, cap=max(1, 128 // hq))
+    grid = (c // bq, pps)
     start = jnp.asarray(start, jnp.int32).reshape(1)
-    return pl.pallas_call(
+    kv_spec = pl.BlockSpec((1, page, hkv, d),
+                           lambda i, j, st, bt: (bt[j], 0, 0, 0))
+    out = pl.pallas_call(
         functools.partial(_paged_prefill_kernel, scale=scale, bq=bq,
-                          page=page, pps=pps, window=window,
-                          logit_cap=logit_cap),
+                          hq=hq, hkv=hkv, page=page, pps=pps,
+                          window=window, logit_cap=logit_cap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bq, d),
-                             lambda h, i, j, st, bt: (h, i, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda h, i, j, st, bt: (bt[j], 0, h // g, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda h, i, j, st, bt: (bt[j], 0, h // g, 0)),
+                pl.BlockSpec((bq * hq, d), lambda i, j, st, bt: (i, 0)),
+                kv_spec,
+                kv_spec,
             ],
-            out_specs=pl.BlockSpec((1, bq, d),
-                                   lambda h, i, j, st, bt: (h, i, 0)),
+            out_specs=pl.BlockSpec((bq * hq, d),
+                                   lambda i, j, st, bt: (i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),   # running max
-                pltpu.VMEM((bq, 1), jnp.float32),   # running denom
-                pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+                pltpu.VMEM((bq * hq, 1), jnp.float32),   # running max
+                pltpu.VMEM((bq * hq, 1), jnp.float32),   # running denom
+                pltpu.VMEM((bq * hq, d), jnp.float32),   # output acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((hq, c, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((c * hq, d), q.dtype),
         interpret=interpret,
-    )(start, block_row, q, k_pages, v_pages)
+    )(start, block_row, q.reshape(c * hq, d), k_pages, v_pages)
+    return out.reshape(c, hq, d)
 
 
 def _paged_latent_prefill_kernel(start_ref, bt_ref, ql_ref, qr_ref,
@@ -327,10 +336,10 @@ def paged_latent_prefill_pallas(q_lat: jax.Array, q_rope: jax.Array,
 
 def _paged_decode_kernel(lengths_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, scale: float, pps: int,
-                         page: int, window: int | None,
+                         page: int, hkv: int, g: int, window: int | None,
                          logit_cap: float | None):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -338,20 +347,26 @@ def _paged_decode_kernel(lengths_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qb = q_ref[0, 0].astype(jnp.float32)         # (G, D)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)   # (page, D)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)
+    d = q_ref.shape[-1]
+    qb = q_ref[0].astype(jnp.float32)            # (Hq, D): row h*G + g
+    # every kv head of the page in one block; row p*Hkv + h of the
+    # flattened tile is position p of kv head h
+    kb = k_ref[0].astype(jnp.float32).reshape(page * hkv, d)
+    vb = v_ref[0].astype(jnp.float32).reshape(page * hkv, d)
     s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
     if logit_cap is not None:
         s = jnp.tanh(s / logit_cap) * logit_cap
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = j * page + col // hkv
     length = lengths_ref[b]
-    mask = pos < length
+    # a query head scores only the columns of its own kv group
+    mask = (row // g == col % hkv) & (pos < length)
     if window is not None:
         mask &= pos >= (length - window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                          # (G, 1)
+    m_prev = m_ref[...]                          # (Hq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
@@ -362,8 +377,8 @@ def _paged_decode_kernel(lengths_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == pps - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -374,43 +389,48 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
                               window: int | None = None,
                               logit_cap: float | None = None,
                               interpret: bool = False) -> jax.Array:
-    """Paged single-token decode: q (B, Hkv, G, D) vs page pools
-    (n_pages, page, Hkv, D) indexed by block_tables (B, pages_per_seq).
+    """Paged single-token decode: q (B, Hq, D) vs page pools
+    (n_pages, page, Hkv, D) indexed by block_tables (B, pages_per_seq);
+    query head h reads kv head h // (Hq / Hkv).
 
     Block tables and lengths ride scalar prefetch so the K/V BlockSpec
-    index_map can route each grid step (b, h, j) to the physical page
+    index_map can route each grid step (b, j) to the physical page
     ``bt[b, j]`` — the kernel only ever DMAs the PACO leaf tiles (one
-    (page, D) face per step) that the block table maps, never a dense
-    (B, max_seq) cache.  Grid (B, Hkv, pages_per_seq); the page axis is
-    innermost so the (m, l, acc) online-softmax state stays in VMEM.
+    whole (page, Hkv, D) page per step, all kv heads: the TPU compiler
+    tiles the last two block dims by (8, 128) and refuses a size-1 head
+    block) that the block table maps, never a dense (B, max_seq) cache.
+    All Hq query heads score the flattened (page*Hkv, D) tile in one
+    dot and mask out the other groups' columns.  Grid
+    (B, pages_per_seq); the page axis is innermost so the (m, l, acc)
+    online-softmax state stays in VMEM.  Returns (B, Hq, D).
     """
-    b, hkv, g, d = q.shape
+    b, hq, d = q.shape
     pps = block_tables.shape[1]
-    page = k_pages.shape[1]
-    grid = (b, hkv, pps)
+    _, page, hkv, _ = k_pages.shape
+    grid = (b, pps)
+    kv_spec = pl.BlockSpec((1, page, hkv, d),
+                           lambda b, j, lens, bt: (bt[b, j], 0, 0, 0))
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, pps=pps,
-                          page=page, window=window, logit_cap=logit_cap),
+                          page=page, hkv=hkv, g=hq // hkv, window=window,
+                          logit_cap=logit_cap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, d),
-                             lambda b, h, j, lens, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda b, h, j, lens, bt: (bt[b, j], 0, h, 0)),
-                pl.BlockSpec((1, page, 1, d),
-                             lambda b, h, j, lens, bt: (bt[b, j], 0, h, 0)),
+                pl.BlockSpec((1, hq, d), lambda b, j, lens, bt: (b, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d),
-                                   lambda b, h, j, lens, bt: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, hq, d),
+                                   lambda b, j, lens, bt: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),   # running max
-                pltpu.VMEM((g, 1), jnp.float32),   # running denom
-                pltpu.VMEM((g, d), jnp.float32),   # output accumulator
+                pltpu.VMEM((hq, 1), jnp.float32),   # running max
+                pltpu.VMEM((hq, 1), jnp.float32),   # running denom
+                pltpu.VMEM((hq, d), jnp.float32),   # output accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
         interpret=interpret,
     )(lengths, block_tables, q, k_pages, v_pages)
 

@@ -1,10 +1,11 @@
 """jit'd public wrappers for flash + paged attention (layout adapters).
 
-Models use (B, S, H, D) layout; the kernels use (B, H, S, D).  On real TPU
-``use_kernel=True`` swaps the Pallas kernel in; on CPU the chunked-jnp
-formulation in repro.models.layers.attention (and the paged-gather
-formulation in ``paged_decode_attention`` below) is the production
-lowering.
+Models use (B, S, H, D) layout; the flash kernel uses (B, H, S, D).  The
+served path runs the jnp formulations on every backend: the chunked
+``repro.models.layers.attention`` and the paged-gather bodies below.  No
+model path passes ``use_kernel=True``; the Pallas kernels it selects are
+reached from the kernel tests (interpret mode against the dense oracles
+of ``ref``) and the TPU-compile tests (``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -63,12 +64,11 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     ``ref.paged_prefill_ref``.
     """
     if use_kernel:
-        _, c, hq, d = q.shape
         o = paged_flash_prefill_pallas(
-            q[0].transpose(1, 0, 2), k_pages, v_pages, block_row, start,
-            scale=1.0 / math.sqrt(d), window=window, logit_cap=logit_cap,
-            interpret=interpret)
-        return o.transpose(1, 0, 2)[None].astype(q.dtype)
+            q[0], k_pages, v_pages, block_row, start,
+            scale=1.0 / math.sqrt(q.shape[-1]), window=window,
+            logit_cap=logit_cap, interpret=interpret)
+        return o[None].astype(q.dtype)
     from repro.models import layers as L  # lazy: models imports kernels
 
     c = q.shape[1]
@@ -139,12 +139,12 @@ def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
     same gather, same grouped-Hkv einsum contraction, same
     mask/softcap/softmax ops — generalized to W query positions with a
     per-position causal mask (key position <= lengths[b] + t).  Keeping
-    the formulation IDENTICAL to the decode tick is what makes greedy
-    speculation bit-identical to the fused non-speculative engine
-    (same logits at every accepted position, hence the same argmax and
-    the same residual stream feeding every later layer's cache write);
-    the W=1, mask-equal case IS the decode path, which
-    tests/test_speculative.py pins bitwise.  ``use_kernel=True`` reuses
+    the formulation IDENTICAL to the decode tick keeps greedy
+    speculation's tokens equal to the fused non-speculative engine's
+    (the same logits at every accepted position up to the rounding of
+    a W-row reduction, DESIGN.md §8.8); the W=1, mask-equal case IS
+    the decode path, which tests/test_speculative.py pins bitwise.
+    ``use_kernel=True`` reuses
     the PR 4 paged-PREFILL Pallas kernel (multi-token causal paged
     attention is exactly its job), vmapped over slots with per-slot
     (start=lengths[b], block row) scalar prefetch.  Dense oracle:
@@ -157,10 +157,10 @@ def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
     if use_kernel:
         o = jax.vmap(
             lambda qb, row, st: paged_flash_prefill_pallas(
-                qb.transpose(1, 0, 2), k_pages, v_pages, row, st,
-                scale=scale, window=window, logit_cap=logit_cap,
-                interpret=interpret))(q, block_tables, lengths)
-        return o.transpose(0, 2, 1, 3).astype(q.dtype)  # (B, W, Hq, D)
+                qb, k_pages, v_pages, row, st, scale=scale, window=window,
+                logit_cap=logit_cap, interpret=interpret))(
+            q, block_tables, lengths)
+        return o.astype(q.dtype)                        # (B, W, Hq, D)
     k = gather_kv_pages(k_pages, block_tables)   # (B, S, Hkv, D)
     v = gather_kv_pages(v_pages, block_tables)
     s = k.shape[1]
@@ -252,10 +252,10 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if use_kernel:
-        qk = q.reshape(b, hkv, g, d)
         o = paged_flash_decode_pallas(
-            qk, k_pages, v_pages, block_tables, lengths, scale=scale,
-            window=window, logit_cap=logit_cap, interpret=interpret)
+            q.reshape(b, hq, d), k_pages, v_pages, block_tables, lengths,
+            scale=scale, window=window, logit_cap=logit_cap,
+            interpret=interpret)
         return o.reshape(b, 1, hq, dhv).astype(q.dtype)
     k = gather_kv_pages(k_pages, block_tables)   # (B, S, Hkv, D)
     v = gather_kv_pages(v_pages, block_tables)

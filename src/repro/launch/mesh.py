@@ -6,7 +6,18 @@ sees the real device count).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+from typing import Sequence
+
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto``: the stack shards by
+    ``NamedSharding`` and sharding constraints, not by explicit-sharding
+    types, which ``jax.make_mesh`` would otherwise default to."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,7 +8,7 @@
   ... --mesh 4x2
 
   # speculative decoding (device-side n-gram drafting + batched paged
-  # verify; greedy-only, bit-identical outputs):
+  # verify; greedy-only, the same tokens as plain decode):
   ... --speculate 4
 """
 from __future__ import annotations
@@ -18,8 +18,9 @@ import time
 
 import jax
 
-from repro.compat import make_mesh
 from repro.configs import get_arch
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.serve import Request, ServeEngine
 
@@ -58,7 +59,7 @@ def main() -> None:
                          "lookup, no draft model), verifies the window "
                          "in ONE batched forward, and keeps the greedy-"
                          "correct prefix — up to N+1 tokens per model "
-                         "pass, bit-identical output.  0 plans the "
+                         "pass, the same greedy tokens.  0 plans the "
                          "window as a PACO leaf tile of the cache "
                          "cuboid.  Greedy-only (default sampler).")
     ap.add_argument("--spec-min-accept", type=float, default=0.25,
@@ -76,6 +77,7 @@ def main() -> None:
     ap.add_argument("--mesh", default=None,
                     help="DATAxMODEL host mesh, e.g. 4x2 (default: none)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -21,6 +21,7 @@ from repro.configs import get_arch
 from repro.data.pipeline import DataConfig
 from repro.dist.act_sharding import use_mesh_rules
 from repro.ft.elastic import make_mesh_for
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, Trainer
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--mesh", default="auto",
                     choices=["auto", "production", "multi_pod"])
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -11,7 +11,7 @@ greedy-correct prefix (DESIGN.md §8.8).
 
 Quality of the proposals only moves the ACCEPTANCE RATE, never
 correctness: rejected drafts are rolled back by the verify step, so any
-deterministic proposal function yields bit-identical engine output.
+deterministic proposal function yields the same greedy tokens.
 Prompt-lookup is the classic weight-free drafter (arXiv:2304.04487 /
 "prompt lookup decoding"): it wins exactly on the repeated-structure
 contexts — code, retrieved documents, and the short cycles greedy

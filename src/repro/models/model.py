@@ -220,8 +220,9 @@ def verify_ticks(params: Params, cfg: ArchConfig, tokens: jax.Array,
     acceptance with rollback of rejected writes -> (token blocks
     (N, B, draft_len + 1), accepted-draft counts (N, B), updated
     history, pages); see transformer.verify_ticks_decoder.
-    Greedy-only: tokens and non-null pool contents are bit-identical to
-    the non-speculative ``decode_ticks`` engine."""
+    Greedy-only: the same tokens as the non-speculative ``decode_ticks``
+    engine, and its pool up to rounding at accepted positions
+    (DESIGN.md §8.8)."""
     if cfg.family == "decoder":
         return TF.verify_ticks_decoder(params, cfg, tokens, pages,
                                        block_tables, lengths, active,

@@ -22,8 +22,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.models import layers as L
 
 Params = dict[str, Any]

@@ -545,9 +545,10 @@ def _verify_window(params: Params, cfg: ArchConfig, tokens: jax.Array,
     page).  Every layer scatters the window's K/V (or MLA latents) into
     the pool, then attends through the paged VERIFY attention — the
     decode tick's exact op sequence generalized to W query positions
-    (kernels/attention/ops.paged_verify_attention), which is what keeps
-    each accepted position's logits AND residual stream bit-identical
-    to the non-speculative tick that would have produced them.  Returns
+    (kernels/attention/ops.paged_verify_attention), which keeps each
+    accepted position's logits AND residual stream equal, up to the
+    rounding of W-row against 1-row reductions, to the non-speculative
+    tick that would have produced them (DESIGN.md §8.8).  Returns
     (logits (B, W, V), updated pages); the caller computes greedy
     acceptance and rolls back the rejected tail
     (``verify_ticks_decoder``).
@@ -655,11 +656,12 @@ def verify_ticks_decoder(params: Params, cfg: ArchConfig,
     rather than the correction token); history is returned so the
     scheduler can keep it DEVICE-resident across dispatches (its
     appends mirror the host replay exactly; only slot churn —
-    admit/retire/preempt — forces a host re-upload).  Invariant (pinned
-    by tests/test_speculative.py): tokens and non-null pool contents
-    are BIT-IDENTICAL to running the fused non-speculative
-    ``decode_ticks`` for the same number of emitted tokens —
-    speculation is a pure perf optimization.
+    admit/retire/preempt — forces a host re-upload).  Contract (pinned
+    by tests/test_speculative.py, DESIGN.md §8.8), against running the
+    fused non-speculative ``decode_ticks`` for the same number of
+    emitted tokens: the same greedy tokens; accepted pool positions
+    equal within rounding; rolled-back and null-routed positions
+    bit-exact.
     """
     from repro.models.draft import draft_ngram_propose
 

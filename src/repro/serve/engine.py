@@ -89,10 +89,12 @@ class ServeEngine:
     — drafts come from the device-side n-gram drafter
     (``models.draft_ngram_propose``, ``draft_ngram`` tail length), the
     verify forward scores the whole window in one pass, and rejected
-    drafts are rolled back so tokens AND pool contents stay
-    bit-identical to the non-speculative fused engine.  ``speculate=N``
-    drafts N tokens per window; ``speculate=0`` plans the window as a
-    PACO leaf tile of the cache cuboid (``paging.paco_draft_len``).
+    drafts are rolled back: greedy tokens equal the non-speculative
+    fused engine's, accepted pool positions hold its K/V up to the
+    rounding of a reordered reduction, and rolled-back positions are
+    bit-exact copies.  ``speculate=N`` drafts N tokens per window;
+    ``speculate=0`` plans the window as a PACO leaf tile of the cache
+    cuboid (``paging.paco_draft_len``).
     Greedy-only: combining it with top-k sampling raises (exact
     rejection sampling is the follow-up).
 
@@ -102,9 +104,9 @@ class ServeEngine:
     speculation must never cost throughput on a workload it cannot
     draft (a verify window spends ~W tokens of model compute to emit
     one token at zero acceptance).  Every 16th skipped dispatch runs a
-    speculative PROBE to re-detect workload shifts.  Because
-    speculative and non-speculative dispatches are bit-identical,
-    switching is free — no parity, pool, or scheduling consequence.
+    speculative PROBE to re-detect workload shifts.  Both dispatch kinds
+    emit the same greedy tokens and leave the same pool up to that
+    rounding, so switching has no paging or scheduling consequence.
     The break-even acceptance is backend-dependent (a weight-bandwidth
     -bound accelerator verifies W tokens for nearly the cost of one;
     a compute-bound CPU does not), so tune per deployment; 0 disables
@@ -477,7 +479,8 @@ class ServeEngine:
         acceptance rate of the last 32 verify windows fell below
         ``spec_min_accept`` — then dispatch plain fused decode, probing
         speculatively every 16th dispatch to catch workload shifts.
-        Free to toggle per dispatch: both paths are bit-identical."""
+        Free to toggle per dispatch: both paths emit the same greedy
+        tokens (DESIGN.md §8.8)."""
         if self.draft_len is None:
             return False
         recent = self._spec_recent
@@ -531,7 +534,7 @@ class ServeEngine:
         (block tables sliced to the span's width bucket, last tokens,
         context lengths, active/budget/eos).  One construction site so
         the speculative and non-speculative dispatches can never drift
-        apart — their bit-identical behavior is what makes the
+        apart — their equal greedy tokens are what make the
         acceptance-aware fallback free to switch between them."""
         width = _width_bucket(
             max(self._write_page_range(s, span)[1] for s in live),
@@ -686,6 +689,34 @@ class ServeEngine:
         return self.done
 
     # -- test/debug surface -------------------------------------------------
+
+    def lower_steps(self, sharding=None) -> dict[str, jax.stages.Lowered]:
+        """Lower each jitted step at its widest shapes (full block-table
+        width, ``ticks_per_dispatch`` ticks) without running it; the
+        ``.compile()`` of each is the program the hot loop runs, with its
+        ``memory_analysis()``.  Params and pools are lowered as held
+        (arrays or ShapeDtypeStructs); the per-slot arguments are
+        ShapeDtypeStructs placed on ``sharding`` (None leaves them to
+        jit, as the hot loop's host-made arrays are)."""
+        def arg(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        b, w, n = self.slots, self.pages_per_seq, self.ticks
+        per_slot = (arg((b, w)), arg((b,)), arg((b,), jnp.bool_), arg((b,)),
+                    arg((b,)))   # tables, lens, active, budget, eos
+        with self._mesh_cm():
+            steps = {
+                "prefill_chunk": self._prefill.lower(
+                    self.params, arg((1, self.chunk)), arg(()), arg(()),
+                    arg((2,), jnp.uint32), self.pool.pools, arg((w,))),
+                "decode_ticks": self._decode.lower(
+                    self.params, arg((b,)), self.pool.pools, *per_slot,
+                    arg((n, 2), jnp.uint32))}
+            if self.draft_len is not None:
+                steps["verify_ticks"] = self._verify.lower(
+                    self.params, arg((b,)), self.pool.pools, *per_slot,
+                    arg((b, self.max_seq)), arg((b,)), arg((n,)))
+        return steps
 
     def check_page_invariants(self) -> None:
         """Block-table/pool invariants (tests/test_serve.py): live rows

@@ -71,7 +71,8 @@ def forward_ref(params: Params, cfg: ArchConfig, tokens: jax.Array
     x = params["embed"][tokens] * jnp.asarray(
         math.sqrt(cfg.d_model), params["embed"].dtype)
     positions = jnp.arange(s)
-    windows = [int(w) for w in _layer_windows(cfg, cfg.n_layers)]
+    with jax.ensure_compile_time_eval():   # static even under jit
+        windows = [int(w) for w in _layer_windows(cfg, cfg.n_layers)]
     for i in range(cfg.n_layers):
         blk = jax.tree.map(lambda p: p[i], params["blocks"])
         window = None if windows[i] == _NO_WINDOW else windows[i]
@@ -100,6 +101,11 @@ def forward_ref(params: Params, cfg: ArchConfig, tokens: jax.Array
         cfg.vocab)
 
 
+# one compile per (cfg, context length); run op by op, every op of the
+# loop would compile anew for each new length
+_forward_ref_jit = jax.jit(forward_ref, static_argnums=1)
+
+
 def reference_decode(params: Params, cfg: ArchConfig, prompt: list[int], *,
                      max_new_tokens: int, eos_id: int = -1,
                      max_seq: int = 128) -> list[int]:
@@ -109,7 +115,8 @@ def reference_decode(params: Params, cfg: ArchConfig, prompt: list[int], *,
     ctx = list(prompt)
     out: list[int] = []
     while len(out) < max_new_tokens and len(ctx) < max_seq:
-        logits = forward_ref(params, cfg, jnp.asarray([ctx], jnp.int32))
+        logits = _forward_ref_jit(params, cfg,
+                                  jnp.asarray([ctx], jnp.int32))
         tok = int(jnp.argmax(logits[0, -1]))
         out.append(tok)
         ctx.append(tok)

@@ -1,13 +1,15 @@
 """Speculative decoding suite (ISSUE 5): device-side n-gram drafting,
 batched paged verification, exact greedy acceptance.
 
-(a) BIT-EXACTNESS — the tentpole invariant: ``models.verify_ticks`` must
-    emit exactly the tokens the fused non-speculative ``decode_ticks``
-    would emit, AND leave the page pool bit-identical — accepted window
-    positions carry the same KV bytes the decode tick would have
-    written, rejected positions roll back to their pre-step contents
-    (only the null page, which absorbs out-of-plan garbage by design,
-    is excluded).  Checked for BOTH cache families (GQA + MLA latent).
+(a) THE SPECULATION CONTRACT (DESIGN.md §8.8): ``models.verify_ticks``
+    must emit exactly the greedy tokens the fused non-speculative
+    ``decode_ticks`` would emit; accepted window positions carry the
+    decode tick's KV within ``ACCEPTED_ATOL`` (the verify forward
+    computes them from a W-row operand, which XLA may reduce in another
+    order); rejected and null-routed positions roll back BIT-exactly to
+    their pre-step contents (they are copies).  Only the null page,
+    which absorbs out-of-plan garbage by design, is excluded.  Checked
+    for BOTH cache families (GQA + MLA latent).
 (b) ENGINE PARITY — the speculative engine serves every request
     token-identical to the non-speculative fused engine and to the
     dense reference oracle, across eos-mid-window, max-seq truncation,
@@ -71,29 +73,58 @@ def _assert_parity(engine, params, cfg, done):
 
 
 # ---------------------------------------------------------------------------
-# (a) verify_ticks vs decode_ticks: BIT-identical tokens and pool bytes
+# (a) verify_ticks vs decode_ticks: equal tokens, pool within the contract
 # ---------------------------------------------------------------------------
 
-def _bitwise_vs_decode(arch, draft_len=3, steps=4, ngram=2):
+# Accepted window positions hold K/V that the W-position verify forward
+# computed and the W=1 decode tick computes again: the same dot products,
+# but XLA may reduce a W-row and a 1-row operand in different orders, so
+# float32 results can differ in the last bits (up to 8.3e-7 measured on
+# these reduced configs, jax 0.9 CPU).  1e-5 is tens of ulps at the
+# pools' O(1) magnitudes; a wrong position or a stale value is off by O(1).
+ACCEPTED_ATOL = 1e-5
+
+
+def _assert_pool_contract(pool_v, pool_d, pool0, written, n_pages):
+    """Non-null pool positions in ``written`` ((n_pages + 1, page) bool)
+    equal the decode path's ``pool_d`` within ACCEPTED_ATOL; every other
+    non-null position is bit-identical to the pre-step ``pool0``
+    (rolled back, null-routed or never written: copies)."""
+    acc = written[:n_pages]
+    for name in pool_v:
+        got = np.asarray(pool_v[name])[:, :n_pages]
+        np.testing.assert_array_equal(
+            got[:, ~acc], pool0[name][:, :n_pages][:, ~acc],
+            err_msg=f"leaf {name!r}: a rejected or unwritten position "
+                    f"changed (rollback must restore its exact bytes)")
+        np.testing.assert_allclose(
+            got[:, acc], pool_d[name][:, :n_pages][:, acc], rtol=0,
+            atol=ACCEPTED_ATOL,
+            err_msg=f"leaf {name!r}: an accepted position differs from "
+                    f"the decode tick's K/V beyond ACCEPTED_ATOL")
+
+
+def _bitwise_vs_decode(arch, draft_len=3, steps=4, ngram=2, warm=4):
     """Run verify_ticks and decode_ticks from the SAME engine state and
     require: (1) each slot's emitted tokens are a prefix of the decode
     path's token stream; (2) every accepted window position holds the
-    decode path's exact KV bytes; (3) every other non-null pool byte is
-    untouched (rollback erased the rejected drafts)."""
+    decode path's KV within ACCEPTED_ATOL; (3) every other non-null pool
+    byte is untouched (rollback erased the rejected drafts)."""
     cfg = _cfg(arch)
     params = init_params(cfg, KEY)
-    eng = ServeEngine(params, cfg, slots=2, max_seq=64, page_size=4,
+    eng = ServeEngine(params, cfg, slots=2, max_seq=96, page_size=4,
                       prefill_chunk_len=8)
     eng.submit(Request(uid=0, prompt=[1, 2, 3, 1, 2, 3, 1],
-                       max_new_tokens=50))
-    eng.submit(Request(uid=1, prompt=[9, 9, 9, 9, 9], max_new_tokens=50))
+                       max_new_tokens=80))
+    eng.submit(Request(uid=1, prompt=[9, 9, 9, 9, 9], max_new_tokens=80))
     eng._admit()
-    # warm the contexts with NON-speculative dispatches first: greedy
-    # decode of a random-init model falls into short cycles after ~10-20
-    # tokens, which is where the n-gram drafter starts matching — the
-    # comparison then exercises BOTH the accepted-write and the
-    # rolled-back branch.
-    for _ in range(2):
+    # warm the contexts with ``warm`` NON-speculative dispatches first:
+    # greedy decode of a random-init model falls into short cycles after
+    # ~10-40 tokens, which is where the n-gram drafter starts matching —
+    # the comparison then exercises BOTH the accepted-write and the
+    # rolled-back branch (the counts are per-arch: where each reduced
+    # model's stream starts to repeat under the installed PRNG).
+    for _ in range(warm):
         eng.tick()
     w = draft_len + 1
     span = steps * w
@@ -126,7 +157,7 @@ def _bitwise_vs_decode(arch, draft_len=3, steps=4, ngram=2):
 
     total_accepted = 0
     n_pages = eng.pool.n_pages                          # null page excluded
-    expected = {k: v.copy() for k, v in pool0.items()}
+    written = np.zeros((n_pages + 1, eng.page), bool)
     for slot in range(2):
         emitted = [int(t) for t in blocks_v[:, slot].ravel() if t >= 0]
         m = len(emitted)
@@ -138,20 +169,13 @@ def _bitwise_vs_decode(arch, draft_len=3, steps=4, ngram=2):
         # (1) tokens: exactly the non-speculative stream's prefix
         assert emitted == [int(t) for t in block_d[:m, slot]], \
             (slot, emitted, block_d[:, slot])
-        # (2) expected pool: the decode path's bytes at the m written
-        # positions, the original bytes everywhere else
+        # (2)+(3): the m written positions carry the decode path's KV,
+        # every other position its original bytes
         for t in range(m):
             pos = int(lens0[slot]) + t
-            pid = int(eng.tables.row(slot)[pos // eng.page])
-            off = pos % eng.page
-            for name in expected:
-                expected[name][:, pid, off] = pool_d[name][:, pid, off]
-    for name in expected:
-        np.testing.assert_array_equal(
-            pool_v[name][:, :n_pages], expected[name][:, :n_pages],
-            err_msg=f"leaf {name!r}: speculative pool diverged (accepted "
-                    f"writes must be bit-identical, rejected writes must "
-                    f"roll back)")
+            written[int(eng.tables.row(slot)[pos // eng.page]),
+                    pos % eng.page] = True
+    _assert_pool_contract(pool_v, pool_d, pool0, written, n_pages)
     # the run must actually have accepted drafts, or the test is vacuous
     assert total_accepted > 0, "no draft was ever accepted"
 
@@ -161,7 +185,7 @@ def test_verify_ticks_bitwise_gqa():
 
 
 def test_verify_ticks_bitwise_mla_latent():
-    _bitwise_vs_decode("deepseek-v2-236b")
+    _bitwise_vs_decode("deepseek-v2-236b", warm=5)
 
 
 def test_verify_ticks_bitwise_window_softcap():
@@ -172,8 +196,9 @@ def test_verify_ticks_bitwise_window_softcap():
 
 def test_verify_rollback_under_budget_cap():
     """A slot with budget 1 still verifies a full window; everything past
-    its single emitted token must roll back / null-route, leaving the
-    non-null pool equal to one decode tick's result."""
+    its single emitted token must roll back / null-route bit-exactly,
+    leaving the non-null pool equal to one decode tick's result (the
+    emitted position within ACCEPTED_ATOL)."""
     cfg = _cfg()
     params = init_params(cfg, KEY)
     eng = ServeEngine(params, cfg, slots=2, max_seq=32, page_size=4,
@@ -198,15 +223,16 @@ def test_verify_rollback_under_budget_cap():
         jnp.asarray(eng._hist), lens0 + 1,   # plan maps ONE position
         jnp.zeros((1,), jnp.int32), max_seq=eng.max_seq, draft_len=3)
     blocks_v = np.asarray(blocks_v)
+    n_pages = eng.pool.n_pages
+    written = np.zeros((n_pages + 1, eng.page), bool)
     for slot in range(2):
         emitted = [int(t) for t in blocks_v[:, slot].ravel() if t >= 0]
         assert emitted == [int(np.asarray(block_d)[0, slot])]
-    n_pages = eng.pool.n_pages
+        pos = int(lens0[slot])
+        written[int(eng.tables.row(slot)[pos // eng.page]),
+                pos % eng.page] = True
     pool_d = {k: np.asarray(v) for k, v in pool_d.items()}
-    for name in pool_v:
-        np.testing.assert_array_equal(
-            np.asarray(pool_v[name])[:, :n_pages],
-            pool_d[name][:, :n_pages])
+    _assert_pool_contract(pool_v, pool_d, pool0, written, n_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -193,7 +193,7 @@ def test_paged_serve_sharded_parity():
     c_kv/k_rope pools)."""
     out = run_py("""
         import dataclasses, jax
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.models import init_params
         from repro.serve import Request, ServeEngine, reference_decode
@@ -229,7 +229,7 @@ def test_paged_serve_sharded_speculative_parity():
     families."""
     out = run_py("""
         import dataclasses, jax
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.models import init_params
         from repro.serve import Request, ServeEngine, reference_decode
@@ -277,7 +277,7 @@ def test_sharded_forward_matches_unsharded():
     """
     out = run_py("""
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.models import init_params, forward
         from repro.dist import act_sharding as act, sharding as D
