@@ -1,5 +1,5 @@
-"""Serving benchmark: sustained tok/s, TTFT, prefill tok/s, decode
-latency — fused multi-tick hot loop vs the PR 3 single-tick old path.
+"""Serving benchmark: sustained tok/s and TTFT — fused multi-tick hot
+loop vs the legacy single-tick decode loop.
 
 Two cache families on the paged continuous-batching engine
 (BENCH_serve.json; re-generate with
@@ -13,9 +13,9 @@ Two cache families on the paged continuous-batching engine
     through the decode step) — so the fused path's decode speedup is
     recorded in the baseline, not just claimed (``decode_speedup_s16``,
     a top-level payload key).  Both modes share the new prefill path
-    (donated pool, batched first-token sync), so the legacy row's
-    prefill/TTFT columns are NOT a PR 3 measurement — only its decode
-    columns are;
+    (donated pool, batched first-token sync), so the legacy row's TTFT
+    column is not the old path's, and its tok_s mixes that shared
+    prefill with the old decode loop;
   * deepseek-v2-236b-reduced (compressed MLA latent pages, absorbed-W_uk
     decode) at slots=4 — plus the latent cache's reason to exist:
     cache bytes/token of the c_kv/k_rope leaves vs the dense per-head
@@ -26,8 +26,9 @@ Protocol: one full warm drain first (compiles prefill + every decode
 table-width bucket the workload reaches), then (a) TTFT = wall time
 from submit to the first emitted token of a single request on an idle
 engine, min of 3; (b) throughput = a timed drain of 2*slots requests of
-16 new tokens each, with the engine's own phase timers giving prefill
-tok/s, decode tok/s, and per-tick decode latency.  The warm drain also
+16 new tokens each (whole-drain tok/s on the host clock; the engine's
+phase times are spans in a profiler trace, which this tool does not
+record).  The warm drain also
 arms the RECOMPILE GUARD: the fused decode executable cache must not
 grow during the measured drain (same workload, same width buckets —
 growth would mean the hot loop recompiles on tick count or slot churn).
@@ -39,7 +40,7 @@ fused non-speculative baseline drained with the SAME weights and
 prompts: (a) "repeat" — repeated-structure prompts on the TIED
 reduced model, whose random-init argmax echoes its context
 (reduced-scale stand-in for a genuinely repetitive workload): high
-acceptance, decode tok/s must beat the fused baseline; (b)
+acceptance, tok/s must beat the fused baseline; (b)
 "adversarial" — distinct-token short-budget prompts on the UNTIED
 model, where acceptance is honestly near zero: the row records what
 the acceptance-aware fallback (``spec_min_accept``) salvages — after
@@ -119,8 +120,8 @@ def _submit_batch(eng: ServeEngine, n_req: int) -> None:
 
 
 def _reset_phase_stats(eng: ServeEngine) -> None:
-    for k in ("prefill_s", "decode_s", "prefill_tokens", "decode_tokens",
-              "decode_steps", "dispatches", "host_syncs"):
+    for k in ("prefill_tokens", "decode_tokens", "decode_steps",
+              "dispatches", "host_syncs"):
         eng.stats[k] = type(eng.stats[k])(0)
 
 
@@ -162,12 +163,6 @@ def measure(arch: str, slots: int, fused: bool = True) -> dict:
            "ticks_per_dispatch": TICKS if fused else 1,
            "tok_s": round(total / dt, 1),
            "ttft_ms": round(ttft * 1e3, 2),
-           "prefill_tok_s": round(s["prefill_tokens"]
-                                  / max(s["prefill_s"], 1e-9), 1),
-           "decode_tok_s": round(s["decode_tokens"]
-                                 / max(s["decode_s"], 1e-9), 1),
-           "decode_tick_ms": round(s["decode_s"] * 1e3
-                                   / max(s["decode_steps"], 1), 3),
            "decode_dispatches": s["dispatches"],
            # host transfers per generated token: the fused loop syncs
            # one token block per dispatch, the old path one per token.
@@ -239,8 +234,6 @@ def measure_spec(arch: str, slots: int, kind: str,
            "workload": kind,
            "speculate": eng.draft_len,
            "tok_s": round(total / dt, 1),
-           "decode_tok_s": round(s["decode_tokens"]
-                                 / max(s["decode_s"], 1e-9), 1),
            "decode_dispatches": s["dispatches"],
            "decode_tokens_per_sync": round(
                s["decode_tokens"] / max(s["dispatches"], 1), 1),
@@ -265,19 +258,12 @@ def main() -> dict:
             f"tok_s={r['tok_s']}")
         row(f"serve_qwen3-0.6b_s{slots}_ttft", r["ttft_ms"] * 1e3,
             f"ttft_ms={r['ttft_ms']}")
-        row(f"serve_qwen3-0.6b_s{slots}_prefill_tok_s",
-            1e6 / max(r["prefill_tok_s"], 1e-9),
-            f"prefill_tok_s={r['prefill_tok_s']}")
-        row(f"serve_qwen3-0.6b_s{slots}_decode_tick",
-            r["decode_tick_ms"] * 1e3,
-            f"decode_tok_s={r['decode_tok_s']}")
     legacy = measure("qwen3-0.6b", 16, fused=False)
     results["16-legacy"] = legacy
-    row("serve_qwen3-0.6b_s16_legacy_decode_tick",
-        legacy["decode_tick_ms"] * 1e3,
-        f"decode_tok_s={legacy['decode_tok_s']}")
-    speedup = round(results["16"]["decode_tok_s"]
-                    / max(legacy["decode_tok_s"], 1e-9), 2)
+    row("serve_qwen3-0.6b_s16_legacy_tok_s", 1e6 / max(legacy["tok_s"], 1e-9),
+        f"tok_s={legacy['tok_s']}")
+    speedup = round(results["16"]["tok_s"]
+                    / max(legacy["tok_s"], 1e-9), 2)
     row("serve_qwen3-0.6b_s16_decode_speedup", 1e6 / max(speedup, 1e-9),
         f"fused/legacy={speedup}x")
     # speculative rows: spec vs fused baseline on the SAME prompt set,
@@ -289,14 +275,13 @@ def main() -> dict:
         spec = measure_spec("qwen3-0.6b", 8, kind, SPEC_DRAFT)
         results[f"8-fused-{kind}"] = base
         results[f"8-spec-{kind}"] = spec
-        ratio = round(spec["decode_tok_s"]
-                      / max(base["decode_tok_s"], 1e-9), 2)
+        ratio = round(spec["tok_s"] / max(base["tok_s"], 1e-9), 2)
         spec_speedups[kind] = ratio
-        row(f"serve_qwen3-0.6b_s8_spec_{kind}_decode",
-            1e6 / max(spec["decode_tok_s"], 1e-9),
-            f"decode_tok_s={spec['decode_tok_s']} "
+        row(f"serve_qwen3-0.6b_s8_spec_{kind}_tok_s",
+            1e6 / max(spec["tok_s"], 1e-9),
+            f"tok_s={spec['tok_s']} "
             f"acc={spec['acceptance_rate']} vs fused "
-            f"{base['decode_tok_s']} ({ratio}x)")
+            f"{base['tok_s']} ({ratio}x)")
     r = measure("deepseek-v2-236b", 4)
     results["mla"] = r
     row("serve_deepseek-v2_s4_tok_s", 1e6 / max(r["tok_s"], 1e-9),
@@ -328,7 +313,7 @@ if __name__ == "__main__":
                            "not the value.  '16' is the fused multi-tick "
                            "engine, '16-legacy' reruns the PR 3 "
                            "single-tick DECODE loop on the same machine "
-                           "(decode_speedup_s16 = fused/legacy decode "
+                           "(decode_speedup_s16 = fused/legacy drain "
                            "tok/s; both modes share the new prefill "
                            "path, so only the legacy row's decode "
                            "columns are a PR 3 measurement); 'mla' is "
@@ -348,7 +333,7 @@ if __name__ == "__main__":
                            "acceptance is honestly ~0 and the "
                            "acceptance-aware fallback keeps the row "
                            "near the fused baseline "
-                           "(spec_decode_speedup = spec/fused decode "
+                           "(spec_decode_speedup = spec/fused drain "
                            "tok/s per workload, same weights and "
                            "prompts within each pair).",
                    "slots": res["slots"]}

@@ -136,9 +136,9 @@ def drain(engine, prompts, *, timed: bool) -> dict[int, list[int]]:
         say(f"  time to first token from submission (closed loop, "
             f"{len(reqs)} requests on {engine.slots} slots): median "
             f"{statistics.median(ttft):.3f}s max {ttft[-1]:.3f}s")
-        say(f"  decode {d['decode_tokens']} tokens in {d['decode_s']:.3f}s "
-            f"= {d['decode_tokens'] / d['decode_s']:.1f} tok/s; prefill "
-            f"{d['prefill_tokens']} tokens in {d['prefill_s']:.3f}s")
+        say(f"  drain {d['decode_tokens']} decode + {d['prefill_tokens']} "
+            f"prompt tokens in {wall:.3f}s = "
+            f"{d['decode_tokens'] / wall:.1f} decode tok/s")
     say(f"  engine.stats (cumulative) {engine.stats}")
     return {r.uid: list(r.out) for r in reqs}
 

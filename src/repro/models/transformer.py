@@ -87,6 +87,52 @@ def _block_apply(p: Params, cfg: ArchConfig, x: jax.Array,
     return act.residual(x + f)
 
 
+# Serving step programs name their parts with these leaf scopes
+# (``jax.named_scope``; op metadata only, the compiled program is the
+# same): ``embed``, ``attn_in`` (ln1, q/k/v projections, rope, qk-norm),
+# ``kv_write`` (cache write addresses and the pool scatter),
+# ``attention`` (the paged attention op), ``attn_out`` (output
+# projection, post-norm, residual), ``mlp`` (ln2, MLP/MoE, post-norm,
+# residual), ``head`` (final norm, head matmul, softcap, vocab mask) and
+# ``sample``.  The layer scan itself is left unscoped, so an op that
+# carries none of them was put there by the scan's slicing of the pool or
+# by the compiler.
+
+def _embed(params: Params, cfg: ArchConfig, tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["embed"][tokens] * jnp.asarray(
+            math.sqrt(cfg.d_model), params["embed"].dtype)
+
+
+def _head(params: Params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
+    """Final norm, head matmul, softcap, vocab mask -> float32 logits."""
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"])
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        return L.mask_vocab(
+            L.softcap((x @ head).astype(jnp.float32), cfg.softcap_logits),
+            cfg.vocab)
+
+
+def _attn_out_mlp(blk: Params, cfg: ArchConfig, x: jax.Array,
+                  a: jax.Array) -> jax.Array:
+    """A layer's tail after its output projection: post-norm and residual
+    (``attn_out``), then the MLP/MoE with its norms and residual
+    (``mlp``)."""
+    with jax.named_scope("attn_out"):
+        if "ln1_post" in blk:
+            a = L.rms_norm(a, blk["ln1_post"])
+        x = x + a
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(x, blk["ln2"])
+        f = (M.apply_moe(blk["mlp"], cfg, h) if cfg.moe
+             else L.apply_mlp(blk["mlp"], cfg, h))
+        if "ln2_post" in blk:
+            f = L.rms_norm(f, blk["ln2_post"])
+        return x + f
+
+
 def forward_decoder(params: Params, cfg: ArchConfig, tokens: jax.Array, *,
                     remat: bool = True) -> jax.Array:
     """tokens (B, S) -> logits (B, S, V)."""
@@ -313,66 +359,62 @@ def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
     b, c = tokens.shape
     page = next(iter(pages.values())).shape[2]
     assert c % page == 0, (c, page)
-    x = params["embed"][tokens] * jnp.asarray(
-        math.sqrt(cfg.d_model), params["embed"].dtype)
-    x = act.batch_seq(x)
+    x = act.batch_seq(_embed(params, cfg, tokens))
     positions = start + jnp.arange(c)
     windows = _layer_windows(cfg, cfg.n_layers)
     # pages this chunk fills: block_row[start/page : start/page + C/page]
-    page_ids = jax.lax.dynamic_slice(block_row, (start // page,),
-                                     (c // page,))
+    with jax.named_scope("kv_write"):
+        page_ids = jax.lax.dynamic_slice(block_row, (start // page,),
+                                         (c // page,))
 
     def scatter(pool_l, new):
         """Write this chunk's C positions as C/page WHOLE pages (PACO
         leaf-tile scatter, no read-modify-write): new (1, C, *feat)."""
-        return pool_l.at[page_ids].set(
-            new.reshape(c // page, page, *new.shape[2:]))
+        with jax.named_scope("kv_write"):
+            return pool_l.at[page_ids].set(
+                new.reshape(c // page, page, *new.shape[2:]))
 
     def body(x, inp):
         blk, window, pg = inp
-        h = L.rms_norm(x, blk["ln1"])
         if cfg.attn == "mla":
-            c_kv, k_rope = L.mla_latents(blk["attn"], cfg, h, positions)
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                c_kv, k_rope = L.mla_latents(blk["attn"], cfg, h, positions)
             pg = {"c_kv": scatter(pg["c_kv"], c_kv),
                   "k_rope": scatter(pg["k_rope"], k_rope)}
             # absorbed latent attention straight off the slot's pages
             # (past pages + this chunk); stale/future page contents are
             # masked by the global causal rule inside the paged op.
-            q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h, positions)
-            o_lat = A.paged_latent_prefill_attention(
-                q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_row, start,
-                scale=L.mla_scale(cfg), q_chunk=cfg.q_chunk)
-            a = L.mla_out(blk["attn"], cfg, o_lat)
+            with jax.named_scope("attn_in"):
+                q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
+                                                 positions)
+            with jax.named_scope("attention"):
+                o_lat = A.paged_latent_prefill_attention(
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_row,
+                    start, scale=L.mla_scale(cfg), q_chunk=cfg.q_chunk)
+            with jax.named_scope("attn_out"):
+                a = L.mla_out(blk["attn"], cfg, o_lat)
         else:
-            q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
             pg = {"k": scatter(pg["k"], kk), "v": scatter(pg["v"], v)}
             # paged-prefill attention over the slot's whole context (past
             # pages + this chunk); unwritten/future positions are masked
             # by the causal rule (k_pos > q_pos), stale contents included.
             # Pallas lowering: kernels.attention.paged_flash_prefill_pallas.
-            o = A.paged_prefill_attention(
-                q, pg["k"], pg["v"], block_row, start, window=window,
-                logit_cap=cfg.softcap_attn, q_chunk=cfg.q_chunk)
-            a = o.reshape(b, c, -1) @ blk["attn"]["wo"]
-        if "ln1_post" in blk:
-            a = L.rms_norm(a, blk["ln1_post"])
-        x = x + a
-        h = L.rms_norm(x, blk["ln2"])
-        f = (M.apply_moe(blk["mlp"], cfg, h) if cfg.moe
-             else L.apply_mlp(blk["mlp"], cfg, h))
-        if "ln2_post" in blk:
-            f = L.rms_norm(f, blk["ln2_post"])
-        return act.residual(x + f), pg
+            with jax.named_scope("attention"):
+                o = A.paged_prefill_attention(
+                    q, pg["k"], pg["v"], block_row, start, window=window,
+                    logit_cap=cfg.softcap_attn, q_chunk=cfg.q_chunk)
+            with jax.named_scope("attn_out"):
+                a = o.reshape(b, c, -1) @ blk["attn"]["wo"]
+        return act.residual(_attn_out_mlp(blk, cfg, x, a)), pg
 
     x, new_pages = jax.lax.scan(
         body, x, (params["blocks"], windows, pages),
         unroll=flags.scan_unroll(cfg.n_layers))
-    x = L.rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = L.mask_vocab(
-        L.softcap((x @ head).astype(jnp.float32), cfg.softcap_logits),
-        cfg.vocab)
-    return logits[0], new_pages
+    return _head(params, cfg, x)[0], new_pages
 
 
 def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
@@ -398,63 +440,61 @@ def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
 
     b = tokens.shape[0]
     page = next(iter(pages.values())).shape[2]
-    x = params["embed"][tokens] * jnp.asarray(
-        math.sqrt(cfg.d_model), params["embed"].dtype)  # (B,1,D)
+    x = _embed(params, cfg, tokens)  # (B,1,D)
     positions = lengths
     windows = _layer_windows(cfg, cfg.n_layers)
     # block_tables may be width-sliced to the live context (the engine
     # caps the jnp gather's materialization); out-of-range rows of
     # masked-off slots clamp and are then routed to the null page.
-    write_page = block_tables[jnp.arange(b), lengths // page]  # (B,)
-    write_off = lengths % page
-    if write_mask is not None:
-        if null_page is None:
-            null_page = next(iter(pages.values())).shape[1] - 1
-        write_page = jnp.where(write_mask, write_page, null_page)
+    with jax.named_scope("kv_write"):
+        write_page = block_tables[jnp.arange(b), lengths // page]  # (B,)
+        write_off = lengths % page
+        if write_mask is not None:
+            if null_page is None:
+                null_page = next(iter(pages.values())).shape[1] - 1
+            write_page = jnp.where(write_mask, write_page, null_page)
 
     def body(x, inp):
         blk, window, pg = inp
-        h = L.rms_norm(x, blk["ln1"])
         if cfg.attn == "mla":
-            c_kv_new, k_rope_new = L.mla_latents(
-                blk["attn"], cfg, h, positions[:, None])
-            pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
-                      c_kv_new[:, 0]),
-                  "k_rope": pg["k_rope"].at[write_page, write_off].set(
-                      k_rope_new[:, 0])}
-            q_lat, q_rope = L.mla_absorbed_q(
-                blk["attn"], cfg, h, positions[:, None])
-            o_lat = A.paged_latent_decode_attention(
-                q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
-                lengths + 1, scale=L.mla_scale(cfg))
-            a = L.mla_out(blk["attn"], cfg, o_lat)
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                c_kv_new, k_rope_new = L.mla_latents(
+                    blk["attn"], cfg, h, positions[:, None])
+            with jax.named_scope("kv_write"):
+                pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
+                          c_kv_new[:, 0]),
+                      "k_rope": pg["k_rope"].at[write_page, write_off].set(
+                          k_rope_new[:, 0])}
+            with jax.named_scope("attn_in"):
+                q_lat, q_rope = L.mla_absorbed_q(
+                    blk["attn"], cfg, h, positions[:, None])
+            with jax.named_scope("attention"):
+                o_lat = A.paged_latent_decode_attention(
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
+                    lengths + 1, scale=L.mla_scale(cfg))
+            with jax.named_scope("attn_out"):
+                a = L.mla_out(blk["attn"], cfg, o_lat)
         else:
-            q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions[:, None])
-            pg = {"k": pg["k"].at[write_page, write_off].set(kk[:, 0]),
-                  "v": pg["v"].at[write_page, write_off].set(v[:, 0])}
-            o = A.paged_decode_attention(q, pg["k"], pg["v"], block_tables,
-                                         lengths + 1, window=window,
-                                         logit_cap=cfg.softcap_attn)
-            a = o.reshape(b, 1, -1) @ blk["attn"]["wo"]
-        if "ln1_post" in blk:
-            a = L.rms_norm(a, blk["ln1_post"])
-        x = x + a
-        h = L.rms_norm(x, blk["ln2"])
-        f = (M.apply_moe(blk["mlp"], cfg, h) if cfg.moe
-             else L.apply_mlp(blk["mlp"], cfg, h))
-        if "ln2_post" in blk:
-            f = L.rms_norm(f, blk["ln2_post"])
-        return x + f, pg
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h,
+                                     positions[:, None])
+            with jax.named_scope("kv_write"):
+                pg = {"k": pg["k"].at[write_page, write_off].set(kk[:, 0]),
+                      "v": pg["v"].at[write_page, write_off].set(v[:, 0])}
+            with jax.named_scope("attention"):
+                o = A.paged_decode_attention(
+                    q, pg["k"], pg["v"], block_tables, lengths + 1,
+                    window=window, logit_cap=cfg.softcap_attn)
+            with jax.named_scope("attn_out"):
+                a = o.reshape(b, 1, -1) @ blk["attn"]["wo"]
+        return _attn_out_mlp(blk, cfg, x, a), pg
 
     x, new_pages = jax.lax.scan(
         body, x, (params["blocks"], windows, pages),
         unroll=flags.scan_unroll(cfg.n_layers))
-    x = L.rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = L.mask_vocab(
-        L.softcap((x @ head).astype(jnp.float32), cfg.softcap_logits),
-        cfg.vocab)
-    return logits[:, 0], new_pages
+    return _head(params, cfg, x)[:, 0], new_pages
 
 
 def decode_step_paged_decoder(params: Params, cfg: ArchConfig,
@@ -511,15 +551,16 @@ def decode_ticks_decoder(params: Params, cfg: ArchConfig,
         logits, pg = _paged_tick(params, cfg, toks[:, None], pg,
                                  block_tables, lens, write_mask=act,
                                  null_page=null_page)
-        nxt = sample_tokens(logits, key=key, top_k=top_k,
-                            temperature=temperature)
-        nxt = jnp.where(act, nxt, toks)        # freeze inactive lanes
-        lens = lens + act                      # the old token's KV landed
-        bud = bud - act
-        # _emit's retirement rule on the just-emitted token: after the
-        # emit, prompt+out == lens + 1 (the new token's KV is unwritten)
-        done = (bud <= 0) | (nxt == eos) | (lens + 1 >= max_seq)
-        out_t = jnp.where(act, nxt, -1)
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits, key=key, top_k=top_k,
+                                temperature=temperature)
+            nxt = jnp.where(act, nxt, toks)    # freeze inactive lanes
+            lens = lens + act                  # the old token's KV landed
+            bud = bud - act
+            # _emit's retirement rule on the just-emitted token: after the
+            # emit, prompt+out == lens + 1 (the new token's KV is unwritten)
+            done = (bud <= 0) | (nxt == eos) | (lens + 1 >= max_seq)
+            out_t = jnp.where(act, nxt, -1)
         return (nxt, lens, act & ~done, bud, pg), out_t
 
     (_, _, _, _, pages), toks = jax.lax.scan(
@@ -556,55 +597,51 @@ def _verify_window(params: Params, cfg: ArchConfig, tokens: jax.Array,
     from repro.kernels.attention import ops as A
 
     b, w = tokens.shape
-    x = params["embed"][tokens] * jnp.asarray(
-        math.sqrt(cfg.d_model), params["embed"].dtype)      # (B, W, D)
+    x = _embed(params, cfg, tokens)                          # (B, W, D)
     positions = lengths[:, None] + jnp.arange(w)[None, :]   # (B, W)
     windows = _layer_windows(cfg, cfg.n_layers)
 
     def body(x, inp):
         blk, window, pg = inp
-        h = L.rms_norm(x, blk["ln1"])
         if cfg.attn == "mla":
-            c_kv_new, k_rope_new = L.mla_latents(
-                blk["attn"], cfg, h, positions)
-            pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
-                      c_kv_new),
-                  "k_rope": pg["k_rope"].at[write_page, write_off].set(
-                      k_rope_new)}
-            q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
-                                             positions)
-            o_lat = A.paged_latent_verify_attention(
-                q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
-                lengths, scale=L.mla_scale(cfg))
-            a = L.mla_out(blk["attn"], cfg, o_lat)
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                c_kv_new, k_rope_new = L.mla_latents(
+                    blk["attn"], cfg, h, positions)
+            with jax.named_scope("kv_write"):
+                pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
+                          c_kv_new),
+                      "k_rope": pg["k_rope"].at[write_page, write_off].set(
+                          k_rope_new)}
+            with jax.named_scope("attn_in"):
+                q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
+                                                 positions)
+            with jax.named_scope("attention"):
+                o_lat = A.paged_latent_verify_attention(
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
+                    lengths, scale=L.mla_scale(cfg))
+            with jax.named_scope("attn_out"):
+                a = L.mla_out(blk["attn"], cfg, o_lat)
         else:
-            q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
-            pg = {"k": pg["k"].at[write_page, write_off].set(kk),
-                  "v": pg["v"].at[write_page, write_off].set(v)}
-            o = A.paged_verify_attention(q, pg["k"], pg["v"],
-                                         block_tables, lengths,
-                                         window=window,
-                                         logit_cap=cfg.softcap_attn)
-            a = o.reshape(b, w, -1) @ blk["attn"]["wo"]
-        if "ln1_post" in blk:
-            a = L.rms_norm(a, blk["ln1_post"])
-        x = x + a
-        h = L.rms_norm(x, blk["ln2"])
-        f = (M.apply_moe(blk["mlp"], cfg, h) if cfg.moe
-             else L.apply_mlp(blk["mlp"], cfg, h))
-        if "ln2_post" in blk:
-            f = L.rms_norm(f, blk["ln2_post"])
-        return x + f, pg
+            with jax.named_scope("attn_in"):
+                h = L.rms_norm(x, blk["ln1"])
+                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
+            with jax.named_scope("kv_write"):
+                pg = {"k": pg["k"].at[write_page, write_off].set(kk),
+                      "v": pg["v"].at[write_page, write_off].set(v)}
+            with jax.named_scope("attention"):
+                o = A.paged_verify_attention(q, pg["k"], pg["v"],
+                                             block_tables, lengths,
+                                             window=window,
+                                             logit_cap=cfg.softcap_attn)
+            with jax.named_scope("attn_out"):
+                a = o.reshape(b, w, -1) @ blk["attn"]["wo"]
+        return _attn_out_mlp(blk, cfg, x, a), pg
 
     x, new_pages = jax.lax.scan(
         body, x, (params["blocks"], windows, pages),
         unroll=flags.scan_unroll(cfg.n_layers))
-    x = L.rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = L.mask_vocab(
-        L.softcap((x @ head).astype(jnp.float32), cfg.softcap_logits),
-        cfg.vocab)
-    return logits, new_pages                                 # (B, W, V)
+    return _head(params, cfg, x), new_pages                  # (B, W, V)
 
 
 def verify_ticks_decoder(params: Params, cfg: ArchConfig,
@@ -675,60 +712,65 @@ def verify_ticks_decoder(params: Params, cfg: ArchConfig,
 
     def step(carry, _):
         toks, lens, act, bud, hist, pg = carry
-        props = draft_ngram_propose(hist, lens + 1, draft_len=draft_len,
-                                    ngram=ngram)
-        win = jnp.concatenate([toks[:, None], props], axis=1)  # (B, W)
+        with jax.named_scope("sample"):
+            props = draft_ngram_propose(hist, lens + 1,
+                                        draft_len=draft_len, ngram=ngram)
+            win = jnp.concatenate([toks[:, None], props], axis=1)  # (B, W)
         # pool coordinates of the window; out-of-plan positions (past
         # the mapped write plan, or any position of an inactive slot)
         # are absorbed by the null page, mirroring _paged_tick's
         # write_mask routing.
-        positions = lens[:, None] + offs_w[None, :]            # (B, W)
-        pp = jnp.clip(positions // page, 0, width - 1)
-        wp = jnp.take_along_axis(block_tables, pp, axis=1)
-        in_plan = act[:, None] & (positions < write_limit[:, None])
-        wp = jnp.where(in_plan, wp, null_page)
-        wo = positions % page
-        # pre-step window contents, for rolling back rejected writes
-        old = {name: leaf[:, wp, wo] for name, leaf in pg.items()}
+        with jax.named_scope("kv_write"):
+            positions = lens[:, None] + offs_w[None, :]            # (B, W)
+            pp = jnp.clip(positions // page, 0, width - 1)
+            wp = jnp.take_along_axis(block_tables, pp, axis=1)
+            in_plan = act[:, None] & (positions < write_limit[:, None])
+            wp = jnp.where(in_plan, wp, null_page)
+            wo = positions % page
+            # pre-step window contents, for rolling back rejected writes
+            old = {name: leaf[:, wp, wo] for name, leaf in pg.items()}
         logits, pg = _verify_window(params, cfg, win, pg, block_tables,
                                     lens, wp, wo)
-        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (B, W)
-        ok = (props == g[:, :draft_len]).astype(jnp.int32)
-        acc = jnp.cumprod(ok, axis=1).sum(axis=1)              # (B,)
-        # sequential _emit replay over the window (static W, unrolled):
-        # token j is emittable while the slot is alive and every earlier
-        # draft was accepted; budget/eos/max_seq flip the slot dead at
-        # exactly the scheduler's rule.
-        alive = act
-        new_toks, new_lens, new_bud = toks, lens, bud
-        cols = []
-        for j in range(w):
-            tok_j = g[:, j]
-            can = alive & (j <= acc)
-            cols.append(jnp.where(can, tok_j, -1))
-            new_toks = jnp.where(can, tok_j, new_toks)
-            new_lens = new_lens + can
-            new_bud = new_bud - can
-            done = ((new_bud <= 0) | (tok_j == eos)
-                    | (new_lens + 1 >= max_seq))
-            alive = alive & ~(can & done)
-        out = jnp.stack(cols, axis=1)                          # (B, W)
-        n_emit = new_lens - lens
+        with jax.named_scope("sample"):
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (B, W)
+            ok = (props == g[:, :draft_len]).astype(jnp.int32)
+            acc = jnp.cumprod(ok, axis=1).sum(axis=1)              # (B,)
+            # sequential _emit replay over the window (static W,
+            # unrolled): token j is emittable while the slot is alive and
+            # every earlier draft was accepted; budget/eos/max_seq flip
+            # the slot dead at exactly the scheduler's rule.
+            alive = act
+            new_toks, new_lens, new_bud = toks, lens, bud
+            cols = []
+            for j in range(w):
+                tok_j = g[:, j]
+                can = alive & (j <= acc)
+                cols.append(jnp.where(can, tok_j, -1))
+                new_toks = jnp.where(can, tok_j, new_toks)
+                new_lens = new_lens + can
+                new_bud = new_bud - can
+                done = ((new_bud <= 0) | (tok_j == eos)
+                        | (new_lens + 1 >= max_seq))
+                alive = alive & ~(can & done)
+            out = jnp.stack(cols, axis=1)                          # (B, W)
+            n_emit = new_lens - lens
         # rollback: positions at window offsets >= n_emit revert to
         # their pre-step contents — the pool ends the step exactly as
         # if only the emitted tokens' KV had ever been written.
-        keep = offs_w[None, :] < n_emit[:, None]               # (B, W)
-        for name in pg:
-            cur = pg[name][:, wp, wo]
-            k_mask = keep.reshape((1, b, w) + (1,) * (cur.ndim - 3))
-            pg[name] = pg[name].at[:, wp, wo].set(
-                jnp.where(k_mask, cur, old[name]))
+        with jax.named_scope("kv_write"):
+            keep = offs_w[None, :] < n_emit[:, None]               # (B, W)
+            for name in pg:
+                cur = pg[name][:, wp, wo]
+                k_mask = keep.reshape((1, b, w) + (1,) * (cur.ndim - 3))
+                pg[name] = pg[name].at[:, wp, wo].set(
+                    jnp.where(k_mask, cur, old[name]))
         # history append: emitted token j becomes context index
         # lens + 1 + j; un-emitted lanes are dropped.
-        hidx = jnp.where(keep, lens[:, None] + 1 + offs_w[None, :],
-                         hist.shape[1])
-        hist = hist.at[jnp.arange(b)[:, None], hidx].set(out,
-                                                         mode="drop")
+        with jax.named_scope("sample"):
+            hidx = jnp.where(keep, lens[:, None] + 1 + offs_w[None, :],
+                             hist.shape[1])
+            hist = hist.at[jnp.arange(b)[:, None], hidx].set(out,
+                                                             mode="drop")
         # of the n_emit emitted tokens, min(n_emit, acc) were accepted
         # drafts (the remainder — at most one — is the correction token)
         return ((new_toks, new_lens, alive, new_bud, hist, pg),

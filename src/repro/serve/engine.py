@@ -54,6 +54,11 @@ class Request:
     # instrumentation (tests + launch report)
     prefill_calls: int = 0
     preemptions: int = 0
+    # host clock (time.perf_counter): submitted, first admitted to a slot
+    # (kept across preemption), first token on the host
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_first: float | None = None
 
 
 def _width_bucket(width: int, pages_per_seq: int) -> int:
@@ -235,14 +240,14 @@ class ServeEngine:
                       "preemptions": 0, "retired": 0, "dispatches": 0,
                       "host_syncs": 0, "max_table_width": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
-                      "prefill_s": 0.0, "decode_s": 0.0,
                       "spec_windows": 0, "drafted_tokens": 0,
                       "accepted_tokens": 0, "spec_fallback_dispatches": 0}
 
         def _prefill_fn(p, toks, start, last, key, pg, row):
             logits, pg = prefill_chunk(p, cfg, toks, start, pg, row)
-            tok = sample_tokens(logits[last][None], key=key, top_k=top_k,
-                                temperature=temperature)
+            with jax.named_scope("sample"):
+                tok = sample_tokens(logits[last][None], key=key,
+                                    top_k=top_k, temperature=temperature)
             return tok[0], pg
 
         null_page = self.pool.null_page
@@ -312,6 +317,7 @@ class ServeEngine:
             # from reference_decode (which generates nothing)
             raise ValueError(f"max_new_tokens must be >= 1, got "
                              f"{req.max_new_tokens}")
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def _emit(self, req: Request, tok: int) -> bool:
@@ -378,14 +384,18 @@ class ServeEngine:
             self.active[slot] = req
             self._admit_order[slot] = self._admit_seq
             self._admit_seq += 1
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
             pending.append((slot, self._prefill_slot(slot, req, ctx)))
         if pending:
-            t0 = time.perf_counter()
-            toks = np.asarray(jnp.stack([t for _, t in pending]))
+            with jax.profiler.TraceAnnotation("serve.first_token_sync"):
+                toks = np.asarray(jnp.stack([t for _, t in pending]))
+            now = time.perf_counter()
             self.stats["host_syncs"] += 1
-            self.stats["prefill_s"] += time.perf_counter() - t0
             for (slot, _), tok in zip(pending, toks):
                 req = self.active[slot]
+                if req.t_first is None:
+                    req.t_first = now
                 tok = int(tok)
                 self._last_tok[slot] = tok
                 self._hist[slot, self._ctx_len[slot]] = tok
@@ -406,24 +416,26 @@ class ServeEngine:
         last = jnp.asarray((len(ctx) - 1) % self.chunk, jnp.int32)
         key = self._next_key()
         tok = None
-        t0 = time.perf_counter()
         with self._mesh_cm():
             for i in range(0, len(ctx), self.chunk):
                 width = _width_bucket(-(-(i + self.chunk) // self.page),
                                       self.pages_per_seq)
                 self.stats["max_table_width"] = max(
                     self.stats["max_table_width"], width)
-                row = jnp.asarray(self.tables.row(slot)[:width])
                 toks = ctx[i:i + self.chunk]
                 toks = toks + [0] * (self.chunk - len(toks))
-                tok, self.pool.pools = self._prefill(
-                    self.params, jnp.asarray([toks], jnp.int32),
-                    jnp.asarray(i, jnp.int32), last, key,
-                    self.pool.pools, row)
+                # the chunk's uploads and launch: the runtime may hold the
+                # uploads until the device has run the chunks queued
+                # before them
+                with jax.profiler.TraceAnnotation("serve.prefill_chunk"):
+                    row = jnp.asarray(self.tables.row(slot)[:width])
+                    tok, self.pool.pools = self._prefill(
+                        self.params, jnp.asarray([toks], jnp.int32),
+                        jnp.asarray(i, jnp.int32), last, key,
+                        self.pool.pools, row)
                 req.prefill_calls += 1
                 self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += len(ctx)
-        self.stats["prefill_s"] += time.perf_counter() - t0
         self._ctx_len[slot] = len(ctx)
         self._hist[slot, :len(ctx)] = ctx
         self._hist_dev = None
@@ -500,8 +512,22 @@ class ServeEngine:
     def tick(self) -> int:
         """Admit + one decode dispatch (``ticks_per_dispatch`` fused
         steps — draft/verify steps when speculating; a single step on
-        the legacy path); returns #retired."""
-        self._admit()
+        the legacy path); returns #retired.
+
+        Host spans (``jax.profiler.TraceAnnotation``, on the profiler's
+        clock; free while no trace is recording): ``serve.tick`` around
+        the whole call, and inside it ``serve.admit``
+        (``serve.prefill_chunk`` per chunk launch,
+        ``serve.first_token_sync``), ``serve.map_pages``,
+        ``serve.dispatch_args``, ``serve.decode_launch``,
+        ``serve.decode_sync`` and ``serve.replay`` on the fused
+        paths."""
+        with jax.profiler.TraceAnnotation("serve.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            self._admit()
         if all(r is None for r in self.active):
             return 0
         n = self.ticks if self.fused else 1
@@ -512,7 +538,8 @@ class ServeEngine:
         use_spec = self._use_speculation()
         w = self.draft_len + 1 if use_spec else 1
         span = n * w
-        self._ensure_decode_pages(span)
+        with jax.profiler.TraceAnnotation("serve.map_pages"):
+            self._ensure_decode_pages(span)
         live = [s for s in range(self.slots) if self.active[s] is not None]
         if not live:
             return 0
@@ -556,34 +583,36 @@ class ServeEngine:
         if self.draft_len is not None:   # acceptance-aware fallback hit
             self.stats["spec_fallback_dispatches"] += 1
             self._hist_dev = None   # this dispatch appends host-side only
-        bt, toks, lens, act, bud, eos = self._dispatch_arrays(live, n)
-        keys = jax.random.split(self._next_key(), n)
-        t0 = time.perf_counter()
-        with self._mesh_cm():
+        with jax.profiler.TraceAnnotation("serve.dispatch_args"):
+            bt, toks, lens, act, bud, eos = self._dispatch_arrays(live, n)
+            keys = jax.random.split(self._next_key(), n)
+        with jax.profiler.TraceAnnotation("serve.decode_launch"), \
+                self._mesh_cm():
             block, self.pool.pools = self._decode(
                 self.params, toks, self.pool.pools, bt, lens, act, bud,
                 eos, keys)
-        block = np.asarray(block)   # THE one device->host sync per block
-        self.stats["decode_s"] += time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("serve.decode_sync"):
+            block = np.asarray(block)   # THE one device->host sync
         self.stats["decode_steps"] += n
         self.stats["dispatches"] += 1
         self.stats["host_syncs"] += 1
         finished = 0
-        for slot in live:
-            req = self.active[slot]
-            for t in range(n):
-                tok = int(block[t, slot])
-                self._ctx_len[slot] += 1   # that tick wrote last_tok's KV
-                self._last_tok[slot] = tok
-                self._hist[slot, self._ctx_len[slot]] = tok
-                self.stats["decode_tokens"] += 1
-                if self._emit(req, tok):
-                    # the device flag flipped this slot inactive at the
-                    # same tick (decode_ticks mirrors _emit); later
-                    # block[t', slot] entries are -1 filler.
-                    self._retire(slot)
-                    finished += 1
-                    break
+        with jax.profiler.TraceAnnotation("serve.replay"):
+            for slot in live:
+                req = self.active[slot]
+                for t in range(n):
+                    tok = int(block[t, slot])
+                    self._ctx_len[slot] += 1   # that tick wrote its KV
+                    self._last_tok[slot] = tok
+                    self._hist[slot, self._ctx_len[slot]] = tok
+                    self.stats["decode_tokens"] += 1
+                    if self._emit(req, tok):
+                        # the device flag flipped this slot inactive at
+                        # the same tick (decode_ticks mirrors _emit);
+                        # later block[t', slot] entries are -1 filler.
+                        self._retire(slot)
+                        finished += 1
+                        break
         return finished
 
     def _dispatch_spec(self, live: list[int], n: int) -> int:
@@ -596,61 +625,65 @@ class ServeEngine:
         of each window (and every window of a retired slot)."""
         w = self.draft_len + 1
         span = n * w
-        bt, toks, lens, act, bud, eos = self._dispatch_arrays(live, span)
-        # one past the last position each slot's write plan mapped real
-        # pages for (window writes beyond it are null-routed on device)
-        limit = jnp.asarray(
-            [self._ctx_len[s] + self._planned_writes(s, span)
-             if self.active[s] is not None else 0
-             for s in range(self.slots)], jnp.int32)
-        # device-resident history when the last dispatch's copy is still
-        # valid (no slot churn, no fused fallback in between): the hot
-        # loop then uploads no per-dispatch history at all.
-        hist = (self._hist_dev if self._hist_dev is not None
-                else jnp.asarray(self._hist))
-        steps = jnp.zeros((n,), jnp.int32)   # shape-only: sets N
-        t0 = time.perf_counter()
-        with self._mesh_cm():
+        with jax.profiler.TraceAnnotation("serve.dispatch_args"):
+            bt, toks, lens, act, bud, eos = self._dispatch_arrays(live,
+                                                                  span)
+            # one past the last position each slot's write plan mapped
+            # real pages for (window writes beyond it are null-routed on
+            # device)
+            limit = jnp.asarray(
+                [self._ctx_len[s] + self._planned_writes(s, span)
+                 if self.active[s] is not None else 0
+                 for s in range(self.slots)], jnp.int32)
+            # device-resident history when the last dispatch's copy is
+            # still valid (no slot churn, no fused fallback in between):
+            # the hot loop then uploads no per-dispatch history at all.
+            hist = (self._hist_dev if self._hist_dev is not None
+                    else jnp.asarray(self._hist))
+            steps = jnp.zeros((n,), jnp.int32)   # shape-only: sets N
+        with jax.profiler.TraceAnnotation("serve.decode_launch"), \
+                self._mesh_cm():
             block, accepted, self._hist_dev, self.pool.pools = \
                 self._verify(self.params, toks, self.pool.pools, bt,
                              lens, act, bud, eos, hist, limit, steps)
         # the ONE device->host sync point per dispatch (the tiny
         # accepted-count block rides along with the token block)
-        block = np.asarray(block)
-        accepted = np.asarray(accepted)
-        self.stats["decode_s"] += time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("serve.decode_sync"):
+            block = np.asarray(block)
+            accepted = np.asarray(accepted)
         self.stats["decode_steps"] += n
         self.stats["dispatches"] += 1
         self.stats["host_syncs"] += 1
         finished = 0
-        for slot in live:
-            req = self.active[slot]
-            retired = False
-            for t in range(n):
-                row = [int(x) for x in block[t, slot] if x >= 0]
-                if not row:
-                    break   # slot went inactive in an earlier step
-                self.stats["spec_windows"] += 1
-                self.stats["drafted_tokens"] += self.draft_len
-                # device-reported: a flag-truncated window can end on an
-                # accepted draft, so len(row) - 1 would undercount
-                acc_w = int(accepted[t, slot])
-                self.stats["accepted_tokens"] += acc_w
-                self._spec_recent.append(acc_w)
-                for tok in row:
-                    self._ctx_len[slot] += 1
-                    self._last_tok[slot] = tok
-                    self._hist[slot, self._ctx_len[slot]] = tok
-                    self.stats["decode_tokens"] += 1
-                    if self._emit(req, tok):
-                        # device flags stopped this slot at the same
-                        # token (verify_ticks mirrors _emit)
-                        self._retire(slot)
-                        finished += 1
-                        retired = True
+        with jax.profiler.TraceAnnotation("serve.replay"):
+            for slot in live:
+                req = self.active[slot]
+                retired = False
+                for t in range(n):
+                    row = [int(x) for x in block[t, slot] if x >= 0]
+                    if not row:
+                        break   # slot went inactive in an earlier step
+                    self.stats["spec_windows"] += 1
+                    self.stats["drafted_tokens"] += self.draft_len
+                    # device-reported: a flag-truncated window can end on
+                    # an accepted draft, so len(row) - 1 would undercount
+                    acc_w = int(accepted[t, slot])
+                    self.stats["accepted_tokens"] += acc_w
+                    self._spec_recent.append(acc_w)
+                    for tok in row:
+                        self._ctx_len[slot] += 1
+                        self._last_tok[slot] = tok
+                        self._hist[slot, self._ctx_len[slot]] = tok
+                        self.stats["decode_tokens"] += 1
+                        if self._emit(req, tok):
+                            # device flags stopped this slot at the same
+                            # token (verify_ticks mirrors _emit)
+                            self._retire(slot)
+                            finished += 1
+                            retired = True
+                            break
+                    if retired:
                         break
-                if retired:
-                    break
         return finished
 
     def _dispatch_legacy(self, live: list[int]) -> int:
@@ -658,13 +691,11 @@ class ServeEngine:
         toks = jnp.asarray(self._last_tok, jnp.int32)[:, None]
         lens = jnp.asarray(self._ctx_len, jnp.int32)
         self.stats["max_table_width"] = self.pages_per_seq
-        t0 = time.perf_counter()
         with self._mesh_cm():
             logits, self.pool.pools = self._decode1(
                 self.params, toks, self.pool.pools, self.tables.device(),
                 lens)
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        self.stats["decode_s"] += time.perf_counter() - t0
         self.stats["decode_steps"] += 1
         self.stats["dispatches"] += 1
         self.stats["host_syncs"] += 1

@@ -100,3 +100,51 @@ def test_paco_weight_spec_rules():
     assert _weight_spec(4096, 1024, mesh) == P("model", "data")
     # non-divisible out falls back to in
     assert _weight_spec(1024, 4090, mesh)[0] == "model"
+
+
+def test_compile_cache_key_includes_named_scopes(tmp_path, monkeypatch):
+    """A program that differs from a cached one only in its named scopes
+    is compiled anew, so the profiler's trace shows its own scopes; the
+    same program written on another line is found again."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import repro.launch.compile_cache as C
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(C, "REPO_CACHE_DIR", tmp_path)
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_compilation_cache_include_metadata_in_key",
+        "jax_traceback_in_locations_limit",
+        "jax_persistent_cache_min_compile_time_secs")}
+
+    def compiled(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2
+        return jax.jit(f).lower(jnp.ones(8)).compile().as_text()
+
+    def compiled_here(scope):
+        def f(x):
+            with jax.named_scope(scope): return jnp.sin(x) * 2  # noqa: E701
+        return jax.jit(f).lower(jnp.ones(8)).compile().as_text()
+
+    def entries():
+        return sorted(p.name for p in tmp_path.iterdir())
+
+    try:
+        cc.reset_cache()
+        jax.config.update("jax_enable_compilation_cache", True)
+        assert C.use_compile_cache() == str(tmp_path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        assert "first/sin" in compiled("first")
+        cached = entries()
+        assert cached
+        assert "first/sin" in compiled_here("first")
+        assert entries() == cached
+        assert "second/sin" in compiled("second")
+        assert len(entries()) > len(cached)
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()

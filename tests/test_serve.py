@@ -17,6 +17,9 @@ Plus the paged-attention kernel oracle checks and the regression pin for
 the old dense-engine cache-commit heuristic.
 """
 import dataclasses
+import importlib.util
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -239,6 +242,64 @@ def test_prefill_call_budget(params, cfg):
     for r in done:
         assert r.prefill_calls == -(-len(r.prompt) // 8), \
             (r.uid, r.prefill_calls)
+
+
+def test_request_timestamps_ordered_and_first_admission_kept(params, cfg):
+    """Every served request carries t_submit <= t_admit <= t_first on the
+    host clock; a preempted request keeps the time of its first admission
+    and of its first token through re-admission."""
+    eng = ServeEngine(params, cfg, slots=2, max_seq=32, page_size=4,
+                      pool_pages=10, prefill_chunk_len=8,
+                      ticks_per_dispatch=4)
+    reqs = [Request(uid=i, prompt=p, max_new_tokens=20)
+            for i, p in enumerate([[1, 2, 3, 4, 5], [7, 8, 9], [11, 12]])]
+    for r in reqs:
+        eng.submit(r)
+    seen = {}
+    while eng.queue or any(r is not None for r in eng.active):
+        eng.tick()
+        for r in reqs:
+            if r.t_admit is not None:
+                first = seen.setdefault(r.uid, (r.t_admit, r.t_first))
+                assert (r.t_admit, r.t_first) == first, r.uid
+    assert eng.stats["preemptions"] >= 1
+    assert any(r.preemptions > 0 for r in reqs)
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first, r.uid
+
+
+def test_chip_smoke_timed_drain_reports_from_kept_counts(params, cfg,
+                                                          monkeypatch,
+                                                          capsys):
+    """chip_smoke.py's timed drain at a tiny size on the CPU: what it
+    prints comes from the engine's counts and the drain's wall clock."""
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    monkeypatch.setattr(smoke, "NEW_TOKENS", 4)
+    eng = ServeEngine(params, cfg, slots=2, max_seq=32, page_size=4,
+                      prefill_chunk_len=8, ticks_per_dispatch=4)
+    outs = smoke.drain(eng, [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12]],
+                       timed=True)
+    assert sorted(outs) == [0, 1, 2]
+    assert all(len(o) == 4 for o in outs.values())
+    assert "decode tok/s" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "deepseek-v2-236b"])
+def test_step_programs_carry_every_leaf_scope(arch):
+    """The lowered decode_ticks and prefill_chunk name their parts with
+    the step programs' leaf scopes (op metadata), for GQA and MLA."""
+    cfg = get_arch(arch).reduced()
+    eng = ServeEngine(init_params(cfg, KEY), cfg, slots=2, max_seq=32)
+    steps = eng.lower_steps()
+    for name in ("decode_ticks", "prefill_chunk"):
+        text = steps[name].as_text(debug_info=True)
+        for scope in ("embed", "attn_in", "kv_write", "attention",
+                      "attn_out", "mlp", "head", "sample"):
+            assert re.search(rf'loc\("(?:[^"]*/)?{scope}/[^"]*"\(', text), \
+                (arch, name, scope)
 
 
 def test_paco_page_size_properties():
