@@ -94,9 +94,9 @@ def _block_apply(p: Params, cfg: ArchConfig, x: jax.Array,
 # ``attention`` (the paged attention op), ``attn_out`` (output
 # projection, post-norm, residual), ``mlp`` (ln2, MLP/MoE, post-norm,
 # residual), ``head`` (final norm, head matmul, softcap, vocab mask) and
-# ``sample``.  The layer scan itself is left unscoped, so an op that
-# carries none of them was put there by the scan's slicing of the pool or
-# by the compiler.
+# ``sample``.  The layer scan itself is left unscoped; it carries the
+# page pool in place (``_layer_scan``) and moves no pool bytes of its
+# own, so an op that carries none of these scopes is the compiler's.
 
 def _embed(params: Params, cfg: ArchConfig, tokens: jax.Array) -> jax.Array:
     with jax.named_scope("embed"):
@@ -321,7 +321,10 @@ def decode_step_decoder(params: Params, cfg: ArchConfig, tokens: jax.Array,
 def paged_cache_leaf_specs(cfg: ArchConfig, page_size: int
                            ) -> dict[str, jax.ShapeDtypeStruct]:
     """Shape of ONE KV page, layer-stacked; repro.serve.paging.init_pool
-    adds the physical-page pool dimension.
+    adds the physical-page pool dimension after the layer dim, so each
+    pool leaf is (L, P, page, *feat).  The paged step programs address
+    it as one (L*P, page, *feat) pool, layer l's pages at l*P + p
+    (``_layer_scan``).
 
     Two cache families behind the same pool/block-table machinery
     (DESIGN.md §8.5): GQA pages are (L, page, Hkv, dh) per k/v leaf; MLA
@@ -338,6 +341,37 @@ def paged_cache_leaf_specs(cfg: ArchConfig, page_size: int
     shape = (lyr, page_size, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jax.ShapeDtypeStruct(shape, cfg.dtype),
             "v": jax.ShapeDtypeStruct(shape, cfg.dtype)}
+
+
+def _layer_scan(body, params: Params, cfg: ArchConfig, x: jax.Array,
+                pages: Params) -> tuple[jax.Array, Params]:
+    """Run ``body(x, blk, window, pg, base) -> (x, pg)`` over the layer
+    stack with the page pool as scan CARRY, shared by the three paged
+    step programs.
+
+    Each (L, P, page, *feat) pool leaf is viewed as one (L*P, page,
+    *feat) pool: a bitcast, since L and P are the two major dims and
+    ``dist.sharding.paged_pool_specs`` cuts neither.  Layer l's physical
+    page p is page ``base + p`` with ``base = l*P``, so ``body`` adds
+    ``base`` to its write page ids and block tables.  No per-layer pool
+    slice enters the scan and no restacked pool leaves it, so each
+    layer's scatter updates the one donated buffer in place.
+    """
+    n_layers, n_phys = next(iter(pages.values())).shape[:2]
+    flat = {k: v.reshape(n_layers * n_phys, *v.shape[2:])
+            for k, v in pages.items()}
+
+    def step(carry, inp):
+        x, pg = carry
+        blk, window, layer = inp
+        return body(x, blk, window, pg, layer * n_phys), None
+
+    (x, flat), _ = jax.lax.scan(
+        step, (x, flat),
+        (params["blocks"], _layer_windows(cfg, n_layers),
+         jnp.arange(n_layers)),
+        unroll=flags.scan_unroll(n_layers))
+    return x, {k: flat[k].reshape(v.shape) for k, v in pages.items()}
 
 
 def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
@@ -361,27 +395,28 @@ def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
     assert c % page == 0, (c, page)
     x = act.batch_seq(_embed(params, cfg, tokens))
     positions = start + jnp.arange(c)
-    windows = _layer_windows(cfg, cfg.n_layers)
     # pages this chunk fills: block_row[start/page : start/page + C/page]
     with jax.named_scope("kv_write"):
         page_ids = jax.lax.dynamic_slice(block_row, (start // page,),
                                          (c // page,))
 
-    def scatter(pool_l, new):
-        """Write this chunk's C positions as C/page WHOLE pages (PACO
-        leaf-tile scatter, no read-modify-write): new (1, C, *feat)."""
+    def scatter(pool, new, base):
+        """Write this chunk's C positions as C/page WHOLE pages of the
+        layer at ``base`` (PACO leaf-tile scatter, no read-modify-write):
+        new (1, C, *feat)."""
         with jax.named_scope("kv_write"):
-            return pool_l.at[page_ids].set(
+            return pool.at[page_ids + base].set(
                 new.reshape(c // page, page, *new.shape[2:]))
 
-    def body(x, inp):
-        blk, window, pg = inp
+    def body(x, blk, window, pg, base):
+        with jax.named_scope("attention"):
+            row = block_row + base
         if cfg.attn == "mla":
             with jax.named_scope("attn_in"):
                 h = L.rms_norm(x, blk["ln1"])
                 c_kv, k_rope = L.mla_latents(blk["attn"], cfg, h, positions)
-            pg = {"c_kv": scatter(pg["c_kv"], c_kv),
-                  "k_rope": scatter(pg["k_rope"], k_rope)}
+            pg = {"c_kv": scatter(pg["c_kv"], c_kv, base),
+                  "k_rope": scatter(pg["k_rope"], k_rope, base)}
             # absorbed latent attention straight off the slot's pages
             # (past pages + this chunk); stale/future page contents are
             # masked by the global causal rule inside the paged op.
@@ -390,30 +425,29 @@ def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
                                                  positions)
             with jax.named_scope("attention"):
                 o_lat = A.paged_latent_prefill_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_row,
-                    start, scale=L.mla_scale(cfg), q_chunk=cfg.q_chunk)
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], row, start,
+                    scale=L.mla_scale(cfg), q_chunk=cfg.q_chunk)
             with jax.named_scope("attn_out"):
                 a = L.mla_out(blk["attn"], cfg, o_lat)
         else:
             with jax.named_scope("attn_in"):
                 h = L.rms_norm(x, blk["ln1"])
                 q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
-            pg = {"k": scatter(pg["k"], kk), "v": scatter(pg["v"], v)}
+            pg = {"k": scatter(pg["k"], kk, base),
+                  "v": scatter(pg["v"], v, base)}
             # paged-prefill attention over the slot's whole context (past
             # pages + this chunk); unwritten/future positions are masked
             # by the causal rule (k_pos > q_pos), stale contents included.
             # Pallas lowering: kernels.attention.paged_flash_prefill_pallas.
             with jax.named_scope("attention"):
                 o = A.paged_prefill_attention(
-                    q, pg["k"], pg["v"], block_row, start, window=window,
+                    q, pg["k"], pg["v"], row, start, window=window,
                     logit_cap=cfg.softcap_attn, q_chunk=cfg.q_chunk)
             with jax.named_scope("attn_out"):
                 a = o.reshape(b, c, -1) @ blk["attn"]["wo"]
         return act.residual(_attn_out_mlp(blk, cfg, x, a)), pg
 
-    x, new_pages = jax.lax.scan(
-        body, x, (params["blocks"], windows, pages),
-        unroll=flags.scan_unroll(cfg.n_layers))
+    x, new_pages = _layer_scan(body, params, cfg, x, pages)
     return _head(params, cfg, x)[0], new_pages
 
 
@@ -427,12 +461,15 @@ def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
 
     tokens (B, 1); block_tables (B, pages_per_seq); lengths (B,) current
     context length per slot (the new token lands at position lengths).
-    ``write_mask`` (B,) bool routes masked-off slots' cache writes to the
-    pool's null page — ``null_page`` as told by the pool owner
+    ``write_mask`` (B,) bool routes masked-off slots' cache writes to
+    each layer's null page — ``null_page`` as told by the pool owner
     (serve.paging ``PagePool.null_page``; the last-physical-page
-    fallback matches ``init_pool``'s layout) — their pages and lengths
-    are untouched, which is how the multi-tick scan freezes slots that
-    retire mid-block.  ``write_mask=None`` writes every slot, matching
+    fallback matches ``init_pool``'s layout), offset to layer l's pages
+    like every other page id — their pages and lengths are untouched,
+    which is how the multi-tick scan freezes slots that retire
+    mid-block.  The pool goes through the layer scan as carry
+    (``_layer_scan``), so each layer's scatter lands in the donated pool
+    in place.  ``write_mask=None`` writes every slot, matching
     the block tables the engine builds (inactive slots' rows already
     point at the null page).  Returns (logits (B, V), updated pages).
     """
@@ -442,7 +479,6 @@ def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
     page = next(iter(pages.values())).shape[2]
     x = _embed(params, cfg, tokens)  # (B,1,D)
     positions = lengths
-    windows = _layer_windows(cfg, cfg.n_layers)
     # block_tables may be width-sliced to the live context (the engine
     # caps the jnp gather's materialization); out-of-range rows of
     # masked-off slots clamp and are then routed to the null page.
@@ -454,24 +490,27 @@ def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
                 null_page = next(iter(pages.values())).shape[1] - 1
             write_page = jnp.where(write_mask, write_page, null_page)
 
-    def body(x, inp):
-        blk, window, pg = inp
+    def body(x, blk, window, pg, base):
+        with jax.named_scope("kv_write"):
+            wp = write_page + base
+        with jax.named_scope("attention"):
+            tables = block_tables + base
         if cfg.attn == "mla":
             with jax.named_scope("attn_in"):
                 h = L.rms_norm(x, blk["ln1"])
                 c_kv_new, k_rope_new = L.mla_latents(
                     blk["attn"], cfg, h, positions[:, None])
             with jax.named_scope("kv_write"):
-                pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
+                pg = {"c_kv": pg["c_kv"].at[wp, write_off].set(
                           c_kv_new[:, 0]),
-                      "k_rope": pg["k_rope"].at[write_page, write_off].set(
+                      "k_rope": pg["k_rope"].at[wp, write_off].set(
                           k_rope_new[:, 0])}
             with jax.named_scope("attn_in"):
                 q_lat, q_rope = L.mla_absorbed_q(
                     blk["attn"], cfg, h, positions[:, None])
             with jax.named_scope("attention"):
                 o_lat = A.paged_latent_decode_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], tables,
                     lengths + 1, scale=L.mla_scale(cfg))
             with jax.named_scope("attn_out"):
                 a = L.mla_out(blk["attn"], cfg, o_lat)
@@ -481,19 +520,17 @@ def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
                 q, kk, v = L.gqa_qkv(blk["attn"], cfg, h,
                                      positions[:, None])
             with jax.named_scope("kv_write"):
-                pg = {"k": pg["k"].at[write_page, write_off].set(kk[:, 0]),
-                      "v": pg["v"].at[write_page, write_off].set(v[:, 0])}
+                pg = {"k": pg["k"].at[wp, write_off].set(kk[:, 0]),
+                      "v": pg["v"].at[wp, write_off].set(v[:, 0])}
             with jax.named_scope("attention"):
                 o = A.paged_decode_attention(
-                    q, pg["k"], pg["v"], block_tables, lengths + 1,
+                    q, pg["k"], pg["v"], tables, lengths + 1,
                     window=window, logit_cap=cfg.softcap_attn)
             with jax.named_scope("attn_out"):
                 a = o.reshape(b, 1, -1) @ blk["attn"]["wo"]
         return _attn_out_mlp(blk, cfg, x, a), pg
 
-    x, new_pages = jax.lax.scan(
-        body, x, (params["blocks"], windows, pages),
-        unroll=flags.scan_unroll(cfg.n_layers))
+    x, new_pages = _layer_scan(body, params, cfg, x, pages)
     return _head(params, cfg, x)[:, 0], new_pages
 
 
@@ -599,26 +636,27 @@ def _verify_window(params: Params, cfg: ArchConfig, tokens: jax.Array,
     b, w = tokens.shape
     x = _embed(params, cfg, tokens)                          # (B, W, D)
     positions = lengths[:, None] + jnp.arange(w)[None, :]   # (B, W)
-    windows = _layer_windows(cfg, cfg.n_layers)
 
-    def body(x, inp):
-        blk, window, pg = inp
+    def body(x, blk, window, pg, base):
+        with jax.named_scope("kv_write"):
+            wp = write_page + base
+        with jax.named_scope("attention"):
+            tables = block_tables + base
         if cfg.attn == "mla":
             with jax.named_scope("attn_in"):
                 h = L.rms_norm(x, blk["ln1"])
                 c_kv_new, k_rope_new = L.mla_latents(
                     blk["attn"], cfg, h, positions)
             with jax.named_scope("kv_write"):
-                pg = {"c_kv": pg["c_kv"].at[write_page, write_off].set(
-                          c_kv_new),
-                      "k_rope": pg["k_rope"].at[write_page, write_off].set(
+                pg = {"c_kv": pg["c_kv"].at[wp, write_off].set(c_kv_new),
+                      "k_rope": pg["k_rope"].at[wp, write_off].set(
                           k_rope_new)}
             with jax.named_scope("attn_in"):
                 q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
                                                  positions)
             with jax.named_scope("attention"):
                 o_lat = A.paged_latent_verify_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], block_tables,
+                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], tables,
                     lengths, scale=L.mla_scale(cfg))
             with jax.named_scope("attn_out"):
                 a = L.mla_out(blk["attn"], cfg, o_lat)
@@ -627,20 +665,17 @@ def _verify_window(params: Params, cfg: ArchConfig, tokens: jax.Array,
                 h = L.rms_norm(x, blk["ln1"])
                 q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
             with jax.named_scope("kv_write"):
-                pg = {"k": pg["k"].at[write_page, write_off].set(kk),
-                      "v": pg["v"].at[write_page, write_off].set(v)}
+                pg = {"k": pg["k"].at[wp, write_off].set(kk),
+                      "v": pg["v"].at[wp, write_off].set(v)}
             with jax.named_scope("attention"):
-                o = A.paged_verify_attention(q, pg["k"], pg["v"],
-                                             block_tables, lengths,
-                                             window=window,
+                o = A.paged_verify_attention(q, pg["k"], pg["v"], tables,
+                                             lengths, window=window,
                                              logit_cap=cfg.softcap_attn)
             with jax.named_scope("attn_out"):
                 a = o.reshape(b, w, -1) @ blk["attn"]["wo"]
         return _attn_out_mlp(blk, cfg, x, a), pg
 
-    x, new_pages = jax.lax.scan(
-        body, x, (params["blocks"], windows, pages),
-        unroll=flags.scan_unroll(cfg.n_layers))
+    x, new_pages = _layer_scan(body, params, cfg, x, pages)
     return _head(params, cfg, x), new_pages                  # (B, W, V)
 
 

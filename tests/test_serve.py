@@ -527,6 +527,84 @@ def test_pool_donation_no_copy(arch):
     _assert_parity(eng, params, cfg, done)
 
 
+def _xs_ys_layer_scan(body, params, cfg, x, pages):
+    """Oracle for ``transformer._layer_scan``: the per-layer plumbing
+    the step programs used before the pool became scan carry.  Each
+    layer's (P, page, *feat) pool slice goes in as ``xs`` and comes back
+    restacked as ``ys``, and the layer body addresses it from base 0."""
+    from repro.models import transformer as TF
+
+    def step(x, inp):
+        blk, window, pg = inp
+        return body(x, blk, window, pg, 0)
+
+    return jax.lax.scan(step, x, (params["blocks"],
+                                  TF._layer_windows(cfg, cfg.n_layers),
+                                  pages))
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "deepseek-v2-236b"])
+def test_layer_scan_carries_pool_like_per_layer_slices(arch):
+    """The layer scan carries the whole (L*P)-page pool and offsets each
+    layer's page ids by l*P.  A prefill chunk per slot, then a 4-tick
+    decode_ticks in which slot 1 retires after tick 1, must give the
+    tokens and every pool leaf of the per-layer xs/ys scan bit for bit,
+    and each layer's null page takes that layer's masked writes only."""
+    from repro.models import decode_ticks, paged_cache_leaf_specs, \
+        prefill_chunk
+    from repro.models import transformer as TF
+    from repro.serve import paging
+
+    cfg = dataclasses.replace(get_arch(arch).reduced(), n_layers=3,
+                              tie_embeddings=False)
+    params = init_params(cfg, KEY)
+    page, n_pages, chunk = 4, 8, 8
+    pool = paging.init_pool(paged_cache_leaf_specs(cfg, page), n_pages,
+                            page)
+    null = pool.null_page
+    # stale contents everywhere, so a write to the wrong page shows
+    init = {name: jax.random.normal(jax.random.fold_in(KEY, i), leaf.shape,
+                                    leaf.dtype)
+            for i, (name, leaf) in enumerate(sorted(pool.pools.items()))}
+    tables = jnp.asarray([[0, 1, 4, 5], [2, 3, 6, 7]], jnp.int32)
+    prompts = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6],
+                           [2, 7, 1, 8, 2, 8, 1, 8]], jnp.int32)
+
+    def serve():
+        pages = {k: v.copy() for k, v in init.items()}
+        first = []
+        for s in range(2):
+            logits, pages = prefill_chunk(params, cfg, prompts[s:s + 1],
+                                          jnp.int32(0), pages, tables[s])
+            first.append(jnp.argmax(logits[-1]).astype(jnp.int32))
+        block, pages = decode_ticks(
+            params, cfg, jnp.stack(first), pages, tables,
+            jnp.full((2,), chunk, jnp.int32), jnp.ones((2,), bool),
+            jnp.asarray([100, 2], jnp.int32), jnp.full((2,), -1, jnp.int32),
+            jnp.zeros((4, 2), jnp.uint32), max_seq=16, null_page=null)
+        return np.asarray(jnp.stack(first)), np.asarray(block), \
+            {k: np.asarray(v) for k, v in pages.items()}
+
+    first, block, pages = serve()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(TF, "_layer_scan", _xs_ys_layer_scan)
+        ref_first, ref_block, ref_pages = serve()
+    np.testing.assert_array_equal(first, ref_first)
+    np.testing.assert_array_equal(block, ref_block)
+    assert (block[2:, 1] == -1).all() and (block[:2, 1] >= 0).all()
+    for name in init:
+        np.testing.assert_array_equal(pages[name], ref_pages[name])
+        # slot 1 froze at length 10: ticks 2-3 land on offset 2 of the
+        # null page, in every layer, and touch nothing else there
+        stale = np.asarray(init[name])[:, null]
+        for layer in range(cfg.n_layers):
+            assert (pages[name][layer, null, 2] != stale[layer, 2]).any(), \
+                (name, layer)
+            for off in (0, 1, 3):
+                np.testing.assert_array_equal(pages[name][layer, null, off],
+                                              stale[layer, off])
+
+
 def test_decode_table_width_capped(params, cfg):
     """The jnp paged-gather fallback materializes (slots, width*page)
     cache bytes per tick; the engine must slice the block tables to the

@@ -9,7 +9,9 @@ process may load the TPU library, and every test worker imports this
 file.  The persistent compilation cache is off around these compiles,
 since a program compiled for a described device cannot be read back.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,10 +53,10 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def steps(one_chip):
-    """The engine's own lowered step programs, for qwen3-0.6b at its
-    published widths and depth, with params and pool as placed shapes
-    (a real pool would be 3.8 GB of host memory)."""
+def engine(one_chip):
+    """The serving engine for qwen3-0.6b at its published widths and
+    depth, with params and pool as placed shapes (a real pool would be
+    3.8 GB of host memory)."""
     cfg = get_arch("qwen3-0.6b")
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
@@ -72,16 +74,42 @@ def steps(one_chip):
         engine = ServeEngine(params, cfg, slots=SLOTS, max_seq=MAX_SEQ,
                              speculate=0)
     assert (engine.page, engine.pages_per_seq) == (32, 64)
-    return engine.lower_steps(one_chip)
+    return engine
 
 
-@pytest.mark.parametrize("name", ["prefill_chunk", "decode_ticks",
-                                  "verify_ticks"])
-def test_engine_step_compiles_and_fits_one_chip(steps, name):
-    m = steps[name].compile().memory_analysis()
+@pytest.fixture(scope="module", params=["prefill_chunk", "decode_ticks",
+                                        "verify_ticks"])
+def steps(request, engine, one_chip):
+    """(name, compiled program) of each of the engine's own step
+    programs, lowered at its widest shapes."""
+    name = request.param
+    return name, engine.lower_steps(one_chip)[name].compile()
+
+
+def test_engine_step_compiles_and_fits_one_chip(steps):
+    name, compiled = steps
+    m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total < HBM_BYTES, (name, total)
+
+
+def test_engine_step_updates_pool_in_place(engine, steps):
+    """The layer scan carries the donated pool and scatters into it: the
+    step keeps no pool-sized temporary, and no instruction copies a pool
+    leaf or writes one back whole (a per-layer slice restacked by
+    dynamic-update-slice)."""
+    name, compiled = steps
+    pools = engine.pool.pools.values()
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 4, (name, temp, pool_bytes)
+    leaf_sizes = {math.prod(p.shape) for p in pools}
+    whole = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if (m := re.search(r"= \w+\[([\d,]+)\]\S* "
+                                r"(copy|dynamic-update-slice)\(", line))
+             and math.prod(map(int, m.group(1).split(","))) in leaf_sizes]
+    assert not whole, (name, whole)
 
 
 def _kernel(name, one_chip):
