@@ -63,7 +63,13 @@ NEMOTRON_4_15B = ArchConfig(
     name="nemotron-4-15b", family="decoder",
     n_layers=32, d_model=6144, n_heads=48, n_kv_heads=8, d_ff=24576,
     vocab=256000, head_dim=128, act="sq_relu",
-    notes="[arXiv:2402.16819; unverified] GQA kv=8, squared-ReLU MLP.",
+    notes="[arXiv:2402.16819 §2, Table 1] 32 layers, d 6144, 48 query / "
+          "8 kv heads of 128, squared-ReLU MLP 24576 with no gate, "
+          "untied embeddings, vocab 256000. The program departs from the "
+          "paper's equations in three ways: RMSNorm with zero-centred "
+          "gains for Megatron's layernorm1p; rotary on all 128 features "
+          "(the family's published configs rotate half, unconfirmed for "
+          "the 15B); embeddings scaled by sqrt(d).",
 )
 
 GEMMA2_2B = ArchConfig(
