@@ -8,19 +8,18 @@ One module per paper table/figure:
   bench_dp       — Theorems 6/7 (1D, GAP)
   bench_moe      — framework integration: PACO dispatch in MoE
   bench_elastic  — arbitrary-p elasticity + HETERO straggler model
-  bench_serve    — paged serving engine: tok/s + TTFT (BENCH_serve.json)
 """
 from __future__ import annotations
 
 from benchmarks import (bench_dp, bench_elastic, bench_lcs, bench_mm,
-                        bench_moe, bench_serve, bench_sort, bench_strassen)
+                        bench_moe, bench_sort, bench_strassen)
 from benchmarks.common import flush_header
 
 
 def main() -> None:
     flush_header()
     for mod in (bench_mm, bench_strassen, bench_lcs, bench_sort, bench_dp,
-                bench_moe, bench_elastic, bench_serve):
+                bench_moe, bench_elastic):
         mod.main()
 
 

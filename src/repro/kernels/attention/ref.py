@@ -47,8 +47,8 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     lengths: (B,) valid cache positions per sequence -> (B, 1, Hq, D).
 
     Materializes each sequence's full gathered cache and runs dense f32
-    softmax — the correctness anchor for ops.paged_decode_attention and
-    the Pallas kernel (tests/test_serve.py).
+    softmax — the correctness anchor for ops.paged_attention at W=1 and
+    the Pallas decode kernel (tests/test_serve.py).
     """
     b, _, hq, d = q.shape
     _, page, hkv, _ = k_pages.shape
@@ -87,7 +87,7 @@ def paged_prefill_ref(q: jax.Array, k_pages: jax.Array,
     slot's whole gathered cache and runs dense f32 softmax with the
     GLOBAL causal mask (q_pos = start + offset) — stale/future page
     contents are masked exactly as the kernel masks them.  The
-    correctness anchor for ops.paged_prefill_attention and
+    correctness anchor for ops.paged_attention at B=1 and
     paged_flash_prefill_pallas (tests/test_serve.py).  Returns
     (1, C, Hq, D)."""
     _, c, hq, d = q.shape
@@ -194,8 +194,8 @@ def paged_latent_attention_ref(q_lat: jax.Array, q_rope: jax.Array,
     production path avoids: materializes each sequence's gathered
     latent cache, CONCATENATES the latent pair into per-position keys,
     BROADCASTS them to every head, and runs dense f32 softmax — the
-    correctness anchor for ops.paged_latent_decode_attention and the
-    Pallas latent kernel (tests/test_serve.py).  Returns
+    correctness anchor for ops.paged_latent_attention at W=1 and the
+    Pallas latent decode kernel (tests/test_serve.py).  Returns
     (B, 1, H, kv_lora)."""
     b, _, h, kv = q_lat.shape
     page = ckv_pages.shape[1]

@@ -346,8 +346,8 @@ def paged_cache_leaf_specs(cfg: ArchConfig, page_size: int
 def _layer_scan(body, params: Params, cfg: ArchConfig, x: jax.Array,
                 pages: Params) -> tuple[jax.Array, Params]:
     """Run ``body(x, blk, window, pg, base) -> (x, pg)`` over the layer
-    stack with the page pool as scan CARRY, shared by the three paged
-    step programs.
+    stack with the page pool as scan CARRY (``_paged_forward``'s layer
+    loop).
 
     Each (L, P, page, *feat) pool leaf is viewed as one (L*P, page,
     *feat) pool: a bitcast, since L and P are the two major dims and
@@ -374,6 +374,66 @@ def _layer_scan(body, params: Params, cfg: ArchConfig, x: jax.Array,
     return x, {k: flat[k].reshape(v.shape) for k, v in pages.items()}
 
 
+def _paged_forward(params: Params, cfg: ArchConfig, tokens: jax.Array,
+                   q_start: jax.Array, pages: Params, tables: jax.Array,
+                   write) -> tuple[jax.Array, Params]:
+    """The one paged forward of every serving step program: W tokens per
+    sequence through the layer scan against the page pool.
+
+    tokens (B, W) at global positions ``q_start[b] + t``; tables
+    (B, width) the block tables (possibly sliced to the live width).
+    ``write(pool, new, base) -> pool`` stores one cache leaf's new
+    entries, ``new`` (B, W, *feat), into the (L*P, page, *feat) pool of
+    the layer whose pages start at ``base`` (``_layer_scan``): it is the
+    one thing the programs do differently (prefill scatters whole pages,
+    decode one row per slot with masked slots routed to the null page,
+    verify a window per slot).  Each layer projects its queries and the
+    new cache leaves (dense GQA ``k``/``v`` or MLA latents
+    ``c_kv``/``k_rope``, by leaf name, ``paged_cache_leaf_specs``),
+    writes the leaves, then attends through its cache family's one op
+    (``kernels.attention.ops``), masked causally from each query's own
+    position, so stale page contents never count.  Returns
+    (logits (B, W, V), updated pages).
+    """
+    from repro.kernels.attention import ops as A
+
+    b, w = tokens.shape
+    mla = cfg.attn == "mla"
+    x = _embed(params, cfg, tokens)                          # (B, W, D)
+    positions = q_start[:, None] + jnp.arange(w)[None, :]   # (B, W)
+
+    def body(x, blk, window, pg, base):
+        with jax.named_scope("attention"):
+            tbl = tables + base
+        with jax.named_scope("attn_in"):
+            h = L.rms_norm(x, blk["ln1"])
+            if mla:
+                new = dict(zip(("c_kv", "k_rope"),
+                               L.mla_latents(blk["attn"], cfg, h, positions)))
+                q = L.mla_absorbed_q(blk["attn"], cfg, h, positions)
+            else:
+                q, k, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
+                new = {"k": k, "v": v}
+        pg = {name: write(pool, new[name], base)
+              for name, pool in pg.items()}
+        with jax.named_scope("attention"):
+            if mla:
+                o = A.paged_latent_attention(*q, pg["c_kv"], pg["k_rope"],
+                                             tbl, q_start,
+                                             scale=L.mla_scale(cfg))
+            else:
+                o = A.paged_attention(q, pg["k"], pg["v"], tbl, q_start,
+                                      window=window,
+                                      logit_cap=cfg.softcap_attn)
+        with jax.named_scope("attn_out"):
+            a = (L.mla_out(blk["attn"], cfg, o) if mla
+                 else o.reshape(b, w, -1) @ blk["attn"]["wo"])
+        return _attn_out_mlp(blk, cfg, x, a), pg
+
+    x, pages = _layer_scan(body, params, cfg, x, pages)
+    return _head(params, cfg, x), pages
+
+
 def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
                           tokens: jax.Array, start: jax.Array,
                           pages: Params, block_row: jax.Array
@@ -385,153 +445,54 @@ def prefill_chunk_decoder(params: Params, cfg: ArchConfig,
     of C), so each chunk writes C/page_size WHOLE pages — a scatter of
     PACO leaf tiles, no read-modify-write.  Returns (logits (C, V) for
     every chunk position, updated pages); the engine issues exactly
-    ceil(prompt_len / C) of these jitted calls per admitted request
-    (the per-token teacher-forcing loop this replaces issued prompt_len).
+    ceil(prompt_len / C) of these jitted calls per admitted request.
     """
-    from repro.kernels.attention import ops as A
-
-    b, c = tokens.shape
+    c = tokens.shape[1]
     page = next(iter(pages.values())).shape[2]
     assert c % page == 0, (c, page)
-    x = act.batch_seq(_embed(params, cfg, tokens))
-    positions = start + jnp.arange(c)
     # pages this chunk fills: block_row[start/page : start/page + C/page]
     with jax.named_scope("kv_write"):
         page_ids = jax.lax.dynamic_slice(block_row, (start // page,),
                                          (c // page,))
 
-    def scatter(pool, new, base):
-        """Write this chunk's C positions as C/page WHOLE pages of the
-        layer at ``base`` (PACO leaf-tile scatter, no read-modify-write):
-        new (1, C, *feat)."""
+    def write(pool, new, base):
         with jax.named_scope("kv_write"):
             return pool.at[page_ids + base].set(
                 new.reshape(c // page, page, *new.shape[2:]))
 
-    def body(x, blk, window, pg, base):
-        with jax.named_scope("attention"):
-            row = block_row + base
-        if cfg.attn == "mla":
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                c_kv, k_rope = L.mla_latents(blk["attn"], cfg, h, positions)
-            pg = {"c_kv": scatter(pg["c_kv"], c_kv, base),
-                  "k_rope": scatter(pg["k_rope"], k_rope, base)}
-            # absorbed latent attention straight off the slot's pages
-            # (past pages + this chunk); stale/future page contents are
-            # masked by the global causal rule inside the paged op.
-            with jax.named_scope("attn_in"):
-                q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
-                                                 positions)
-            with jax.named_scope("attention"):
-                o_lat = A.paged_latent_prefill_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], row, start,
-                    scale=L.mla_scale(cfg), q_chunk=cfg.q_chunk)
-            with jax.named_scope("attn_out"):
-                a = L.mla_out(blk["attn"], cfg, o_lat)
-        else:
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
-            pg = {"k": scatter(pg["k"], kk, base),
-                  "v": scatter(pg["v"], v, base)}
-            # paged-prefill attention over the slot's whole context (past
-            # pages + this chunk); unwritten/future positions are masked
-            # by the causal rule (k_pos > q_pos), stale contents included.
-            # Pallas lowering: kernels.attention.paged_flash_prefill_pallas.
-            with jax.named_scope("attention"):
-                o = A.paged_prefill_attention(
-                    q, pg["k"], pg["v"], row, start, window=window,
-                    logit_cap=cfg.softcap_attn, q_chunk=cfg.q_chunk)
-            with jax.named_scope("attn_out"):
-                a = o.reshape(b, c, -1) @ blk["attn"]["wo"]
-        return act.residual(_attn_out_mlp(blk, cfg, x, a)), pg
-
-    x, new_pages = _layer_scan(body, params, cfg, x, pages)
-    return _head(params, cfg, x)[0], new_pages
+    logits, pages = _paged_forward(params, cfg, tokens, start[None], pages,
+                                   block_row[None], write)
+    return logits[0], pages
 
 
-def _paged_tick(params: Params, cfg: ArchConfig, tokens: jax.Array,
-                pages: Params, block_tables: jax.Array, lengths: jax.Array,
-                write_mask: jax.Array | None = None,
-                null_page: int | None = None
-                ) -> tuple[jax.Array, Params]:
-    """One fused paged decode tick over all slots (the shared body of
-    ``decode_step_paged_decoder`` and ``decode_ticks_decoder``).
-
-    tokens (B, 1); block_tables (B, pages_per_seq); lengths (B,) current
-    context length per slot (the new token lands at position lengths).
-    ``write_mask`` (B,) bool routes masked-off slots' cache writes to
-    each layer's null page — ``null_page`` as told by the pool owner
+def _decode_write(pages: Params, tables: jax.Array, lengths: jax.Array,
+                  write_mask: jax.Array | None = None,
+                  null_page: int | None = None):
+    """``_paged_forward``'s ``write`` for one decode tick: each slot's new
+    row lands at position ``lengths`` through its block table.
+    ``write_mask`` (B,) bool routes masked-off slots' writes to each
+    layer's null page — ``null_page`` as told by the pool owner
     (serve.paging ``PagePool.null_page``; the last-physical-page
-    fallback matches ``init_pool``'s layout), offset to layer l's pages
-    like every other page id — their pages and lengths are untouched,
+    fallback matches ``init_pool``'s layout), offset to the layer's
+    pages like every other page id — so their pages are untouched,
     which is how the multi-tick scan freezes slots that retire
-    mid-block.  The pool goes through the layer scan as carry
-    (``_layer_scan``), so each layer's scatter lands in the donated pool
-    in place.  ``write_mask=None`` writes every slot, matching
-    the block tables the engine builds (inactive slots' rows already
-    point at the null page).  Returns (logits (B, V), updated pages).
-    """
-    from repro.kernels.attention import ops as A
-
-    b = tokens.shape[0]
+    mid-block.  Tables may be width-sliced to the live context;
+    out-of-range rows of masked-off slots clamp and are then routed to
+    the null page."""
     page = next(iter(pages.values())).shape[2]
-    x = _embed(params, cfg, tokens)  # (B,1,D)
-    positions = lengths
-    # block_tables may be width-sliced to the live context (the engine
-    # caps the jnp gather's materialization); out-of-range rows of
-    # masked-off slots clamp and are then routed to the null page.
     with jax.named_scope("kv_write"):
-        write_page = block_tables[jnp.arange(b), lengths // page]  # (B,)
+        write_page = tables[jnp.arange(tables.shape[0]), lengths // page]
         write_off = lengths % page
         if write_mask is not None:
             if null_page is None:
                 null_page = next(iter(pages.values())).shape[1] - 1
             write_page = jnp.where(write_mask, write_page, null_page)
 
-    def body(x, blk, window, pg, base):
+    def write(pool, new, base):
         with jax.named_scope("kv_write"):
-            wp = write_page + base
-        with jax.named_scope("attention"):
-            tables = block_tables + base
-        if cfg.attn == "mla":
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                c_kv_new, k_rope_new = L.mla_latents(
-                    blk["attn"], cfg, h, positions[:, None])
-            with jax.named_scope("kv_write"):
-                pg = {"c_kv": pg["c_kv"].at[wp, write_off].set(
-                          c_kv_new[:, 0]),
-                      "k_rope": pg["k_rope"].at[wp, write_off].set(
-                          k_rope_new[:, 0])}
-            with jax.named_scope("attn_in"):
-                q_lat, q_rope = L.mla_absorbed_q(
-                    blk["attn"], cfg, h, positions[:, None])
-            with jax.named_scope("attention"):
-                o_lat = A.paged_latent_decode_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], tables,
-                    lengths + 1, scale=L.mla_scale(cfg))
-            with jax.named_scope("attn_out"):
-                a = L.mla_out(blk["attn"], cfg, o_lat)
-        else:
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h,
-                                     positions[:, None])
-            with jax.named_scope("kv_write"):
-                pg = {"k": pg["k"].at[wp, write_off].set(kk[:, 0]),
-                      "v": pg["v"].at[wp, write_off].set(v[:, 0])}
-            with jax.named_scope("attention"):
-                o = A.paged_decode_attention(
-                    q, pg["k"], pg["v"], tables, lengths + 1,
-                    window=window, logit_cap=cfg.softcap_attn)
-            with jax.named_scope("attn_out"):
-                a = o.reshape(b, 1, -1) @ blk["attn"]["wo"]
-        return _attn_out_mlp(blk, cfg, x, a), pg
+            return pool.at[write_page + base, write_off].set(new[:, 0])
 
-    x, new_pages = _layer_scan(body, params, cfg, x, pages)
-    return _head(params, cfg, x)[:, 0], new_pages
+    return write
 
 
 def decode_step_paged_decoder(params: Params, cfg: ArchConfig,
@@ -546,7 +507,10 @@ def decode_step_paged_decoder(params: Params, cfg: ArchConfig,
     per-slot Python, one compiled step per tick.  Returns
     (logits (B, V), updated pages).
     """
-    return _paged_tick(params, cfg, tokens, pages, block_tables, lengths)
+    write = _decode_write(pages, block_tables, lengths)
+    logits, pages = _paged_forward(params, cfg, tokens, lengths, pages,
+                                   block_tables, write)
+    return logits[:, 0], pages
 
 
 def decode_ticks_decoder(params: Params, cfg: ArchConfig,
@@ -560,7 +524,7 @@ def decode_ticks_decoder(params: Params, cfg: ArchConfig,
                          ) -> tuple[jax.Array, Params]:
     """Fused MULTI-tick decode: N decode steps in one dispatch.
 
-    A ``jax.lax.scan`` over ``decode_step_paged``'s tick body with
+    A ``jax.lax.scan`` of one-token ``_paged_forward`` ticks with
     device-side sampling (``models.sampling.sample_tokens``), cache
     append, block-table advance, and per-slot retirement flags — the
     host syncs ONE small (N, slots) token block per dispatch instead of
@@ -585,9 +549,10 @@ def decode_ticks_decoder(params: Params, cfg: ArchConfig,
 
     def tick(carry, key):
         toks, lens, act, bud, pg = carry
-        logits, pg = _paged_tick(params, cfg, toks[:, None], pg,
-                                 block_tables, lens, write_mask=act,
-                                 null_page=null_page)
+        write = _decode_write(pg, block_tables, lens, act, null_page)
+        logits, pg = _paged_forward(params, cfg, toks[:, None], lens, pg,
+                                    block_tables, write)
+        logits = logits[:, 0]
         with jax.named_scope("sample"):
             nxt = sample_tokens(logits, key=key, top_k=top_k,
                                 temperature=temperature)
@@ -609,76 +574,6 @@ def decode_ticks_decoder(params: Params, cfg: ArchConfig,
 # Speculative decoding: batched paged verify of device-drafted windows
 # ---------------------------------------------------------------------------
 
-def _verify_window(params: Params, cfg: ArchConfig, tokens: jax.Array,
-                   pages: Params, block_tables: jax.Array,
-                   lengths: jax.Array, write_page: jax.Array,
-                   write_off: jax.Array) -> tuple[jax.Array, Params]:
-    """One speculative VERIFY forward: W window tokens per slot in one
-    pass (the multi-token sibling of ``_paged_tick``'s body).
-
-    tokens (B, W): slot b's last emitted token followed by its W-1
-    drafted continuation tokens, at global positions lengths[b] + t.
-    write_page/write_off (B, W): per-position pool coordinates as routed
-    by the caller (out-of-plan positions already point at the null
-    page).  Every layer scatters the window's K/V (or MLA latents) into
-    the pool, then attends through the paged VERIFY attention — the
-    decode tick's exact op sequence generalized to W query positions
-    (kernels/attention/ops.paged_verify_attention), which keeps each
-    accepted position's logits AND residual stream equal, up to the
-    rounding of W-row against 1-row reductions, to the non-speculative
-    tick that would have produced them (DESIGN.md §8.8).  Returns
-    (logits (B, W, V), updated pages); the caller computes greedy
-    acceptance and rolls back the rejected tail
-    (``verify_ticks_decoder``).
-    """
-    from repro.kernels.attention import ops as A
-
-    b, w = tokens.shape
-    x = _embed(params, cfg, tokens)                          # (B, W, D)
-    positions = lengths[:, None] + jnp.arange(w)[None, :]   # (B, W)
-
-    def body(x, blk, window, pg, base):
-        with jax.named_scope("kv_write"):
-            wp = write_page + base
-        with jax.named_scope("attention"):
-            tables = block_tables + base
-        if cfg.attn == "mla":
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                c_kv_new, k_rope_new = L.mla_latents(
-                    blk["attn"], cfg, h, positions)
-            with jax.named_scope("kv_write"):
-                pg = {"c_kv": pg["c_kv"].at[wp, write_off].set(c_kv_new),
-                      "k_rope": pg["k_rope"].at[wp, write_off].set(
-                          k_rope_new)}
-            with jax.named_scope("attn_in"):
-                q_lat, q_rope = L.mla_absorbed_q(blk["attn"], cfg, h,
-                                                 positions)
-            with jax.named_scope("attention"):
-                o_lat = A.paged_latent_verify_attention(
-                    q_lat, q_rope, pg["c_kv"], pg["k_rope"], tables,
-                    lengths, scale=L.mla_scale(cfg))
-            with jax.named_scope("attn_out"):
-                a = L.mla_out(blk["attn"], cfg, o_lat)
-        else:
-            with jax.named_scope("attn_in"):
-                h = L.rms_norm(x, blk["ln1"])
-                q, kk, v = L.gqa_qkv(blk["attn"], cfg, h, positions)
-            with jax.named_scope("kv_write"):
-                pg = {"k": pg["k"].at[wp, write_off].set(kk),
-                      "v": pg["v"].at[wp, write_off].set(v)}
-            with jax.named_scope("attention"):
-                o = A.paged_verify_attention(q, pg["k"], pg["v"], tables,
-                                             lengths, window=window,
-                                             logit_cap=cfg.softcap_attn)
-            with jax.named_scope("attn_out"):
-                a = o.reshape(b, w, -1) @ blk["attn"]["wo"]
-        return _attn_out_mlp(blk, cfg, x, a), pg
-
-    x, new_pages = _layer_scan(body, params, cfg, x, pages)
-    return _head(params, cfg, x), new_pages                  # (B, W, V)
-
-
 def verify_ticks_decoder(params: Params, cfg: ArchConfig,
                          tokens: jax.Array, pages: Params,
                          block_tables: jax.Array, lengths: jax.Array,
@@ -695,8 +590,11 @@ def verify_ticks_decoder(params: Params, cfg: ArchConfig,
     Per step, per slot: (1) the device-side n-gram drafter
     (``models.draft.draft_ngram_propose``) proposes ``draft_len``
     continuation tokens from the slot's own token history; (2) ONE
-    ``_verify_window`` forward scores the W = draft_len + 1 window
-    (last token + drafts) and scatters its K/V into the pool; (3) the
+    ``_paged_forward`` scores the W = draft_len + 1 window (last token
+    + drafts) and scatters its K/V into the pool — each window row
+    through the op sequence of the decode tick at its position, which
+    keeps each accepted position's logits equal to the tick's up to the
+    rounding of W-row against 1-row projections; (3) the
     greedy-acceptance prefix is computed on-device — drafted token t is
     accepted iff every earlier draft matched its argmax and draft[t] ==
     argmax(logits[t]) — and the emitted tokens are argmax[0 ..
@@ -753,7 +651,7 @@ def verify_ticks_decoder(params: Params, cfg: ArchConfig,
             win = jnp.concatenate([toks[:, None], props], axis=1)  # (B, W)
         # pool coordinates of the window; out-of-plan positions (past
         # the mapped write plan, or any position of an inactive slot)
-        # are absorbed by the null page, mirroring _paged_tick's
+        # are absorbed by the null page, mirroring ``_decode_write``'s
         # write_mask routing.
         with jax.named_scope("kv_write"):
             positions = lens[:, None] + offs_w[None, :]            # (B, W)
@@ -764,8 +662,13 @@ def verify_ticks_decoder(params: Params, cfg: ArchConfig,
             wo = positions % page
             # pre-step window contents, for rolling back rejected writes
             old = {name: leaf[:, wp, wo] for name, leaf in pg.items()}
-        logits, pg = _verify_window(params, cfg, win, pg, block_tables,
-                                    lens, wp, wo)
+
+        def write(pool, new, base):
+            with jax.named_scope("kv_write"):
+                return pool.at[wp + base, wo].set(new)
+
+        logits, pg = _paged_forward(params, cfg, win, lens, pg,
+                                    block_tables, write)
         with jax.named_scope("sample"):
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (B, W)
             ok = (props == g[:, :draft_len]).astype(jnp.int32)

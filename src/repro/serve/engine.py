@@ -37,11 +37,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.models import decode_step_paged, decode_ticks, \
-    paged_cache_leaf_specs, prefill_chunk, sample_tokens, verify_ticks
+from repro.models import decode_ticks, paged_cache_leaf_specs, \
+    prefill_chunk, sample_tokens, verify_ticks
 from repro.serve import paging
 
 Params = Any
+# tail length the speculative n-gram drafter matches on
+DRAFT_NGRAM = 2
 
 
 @dataclasses.dataclass
@@ -79,20 +81,15 @@ class ServeEngine:
     host-sync overhead over more tokens (throughput) at the cost of up
     to that many speculative page mappings per slot and token-block
     latency (a token is visible to the host only at the end of its
-    dispatch).  ``fused=False`` keeps the PR 3 single-tick DECODE loop
-    (one dispatch + one host argmax per token, pool undonated through
-    the decode step) — the old-path decode baseline
-    ``benchmarks/bench_serve.py`` records; the prefill path (donated
-    pool, batched first-token sync) is shared by both modes, so only
-    the decode columns compare old-vs-new like for like.
-    ``top_k``/``temperature`` switch the device-side sampler from
-    greedy argmax to top-k categorical (``models.sample_tokens``).
+    dispatch).  ``top_k``/``temperature`` switch the device-side
+    sampler from greedy argmax to top-k categorical
+    (``models.sample_tokens``).
 
     ``speculate`` turns on SPECULATIVE decoding (DESIGN.md §8.8): each
     decode dispatch runs ``ticks_per_dispatch`` draft->verify->accept
     steps, every step advancing each live slot by 1..draft_len+1 tokens
     — drafts come from the device-side n-gram drafter
-    (``models.draft_ngram_propose``, ``draft_ngram`` tail length), the
+    (``models.draft_ngram_propose``, ``DRAFT_NGRAM`` tail length), the
     verify forward scores the whole window in one pass, and rejected
     drafts are rolled back: greedy tokens equal the non-speculative
     fused engine's, accepted pool positions hold its K/V up to the
@@ -122,16 +119,17 @@ class ServeEngine:
                  max_seq: int = 128, page_size: int | None = None,
                  pool_pages: int | None = None,
                  prefill_chunk_len: int | None = None, mesh=None,
-                 ticks_per_dispatch: int = 8, fused: bool = True,
+                 ticks_per_dispatch: int = 8,
                  top_k: int | None = None, temperature: float = 1.0,
-                 speculate: int | None = None, draft_ngram: int = 2,
+                 speculate: int | None = None,
                  spec_min_accept: float = 0.25, seed: int = 0):
         self.cfg = cfg
         self.slots = slots
         self.max_seq = max_seq
-        # the cache cuboid's per-position feature extent: head_dim for
-        # dense GQA KV, the compressed kv_lora face for MLA latents.
-        feat = cfg.mla.kv_lora if cfg.attn == "mla" else cfg.head_dim
+        # the cache cuboid's per-position feature extent, the first
+        # cache leaf's last dim: head_dim for dense GQA KV, the
+        # compressed kv_lora face for MLA latents.
+        feat = next(iter(paged_cache_leaf_specs(cfg, 1).values())).shape[-1]
         self.page = page_size or paging.paco_page_size(
             slots, max_seq, feat)
         if max_seq % self.page != 0:
@@ -161,14 +159,8 @@ class ServeEngine:
         self.chunk = prefill_chunk_len
         assert ticks_per_dispatch >= 1, ticks_per_dispatch
         self.ticks = ticks_per_dispatch
-        self.fused = fused
         self.draft_len = None
-        self.draft_ngram = draft_ngram
         if speculate is not None:
-            if not fused:
-                raise ValueError(
-                    "speculate requires the fused engine (fused=True): "
-                    "the legacy single-tick loop has no verify dispatch")
             if top_k is not None or temperature != 1.0:
                 raise NotImplementedError(
                     f"speculative decoding is greedy-only (got top_k="
@@ -266,14 +258,14 @@ class ServeEngine:
         self._prefill = jax.jit(_prefill_fn, donate_argnums=(5,), **out_sh)
         self._decode = jax.jit(_decode_fn, donate_argnums=(2,), **out_sh)
         if self.draft_len is not None:
-            draft_len, ngram = self.draft_len, self.draft_ngram
+            draft_len = self.draft_len
 
             def _verify_fn(p, toks, pg, bt, lens, act, bud, eos, hist,
                            limit, steps):
                 return verify_ticks(p, cfg, toks, pg, bt, lens, act, bud,
                                     eos, hist, limit, steps,
                                     max_seq=max_seq, draft_len=draft_len,
-                                    ngram=ngram, null_page=null_page)
+                                    ngram=DRAFT_NGRAM, null_page=null_page)
 
             # same donation discipline as _decode; on a mesh the pool
             # out_shardings come from the same helper as placement
@@ -286,14 +278,6 @@ class ServeEngine:
                         D.verify_shardings(cfg, mesh, self.pool.pools)}
             self._verify = jax.jit(_verify_fn, donate_argnums=(2,),
                                    **v_sh)
-        if not fused:
-            # PR 3 old DECODE path: one undonated single-tick step per
-            # token, full-width tables, host-side argmax — kept as the
-            # benchmark baseline the fused decode loop is measured
-            # against (prefill stays on the shared donated path).
-            self._decode1 = jax.jit(
-                lambda p, t, pg, bt, ln: decode_step_paged(p, cfg, t, pg,
-                                                           bt, ln))
 
     # -- plumbing -----------------------------------------------------------
 
@@ -511,8 +495,7 @@ class ServeEngine:
 
     def tick(self) -> int:
         """Admit + one decode dispatch (``ticks_per_dispatch`` fused
-        steps — draft/verify steps when speculating; a single step on
-        the legacy path); returns #retired.
+        steps — draft/verify steps when speculating); returns #retired.
 
         Host spans (``jax.profiler.TraceAnnotation``, on the profiler's
         clock; free while no trace is recording): ``serve.tick`` around
@@ -520,8 +503,7 @@ class ServeEngine:
         (``serve.prefill_chunk`` per chunk launch,
         ``serve.first_token_sync``), ``serve.map_pages``,
         ``serve.dispatch_args``, ``serve.decode_launch``,
-        ``serve.decode_sync`` and ``serve.replay`` on the fused
-        paths."""
+        ``serve.decode_sync`` and ``serve.replay``."""
         with jax.profiler.TraceAnnotation("serve.tick"):
             return self._tick()
 
@@ -530,7 +512,7 @@ class ServeEngine:
             self._admit()
         if all(r is None for r in self.active):
             return 0
-        n = self.ticks if self.fused else 1
+        n = self.ticks
         # speculative dispatches extend the per-slot page pre-mapping
         # from n ticks to n x (draft_len + 1) window positions: every
         # in-plan window write needs a real page even when the draft is
@@ -543,8 +525,6 @@ class ServeEngine:
         live = [s for s in range(self.slots) if self.active[s] is not None]
         if not live:
             return 0
-        if not self.fused:
-            return self._dispatch_legacy(live)
         # clamp the block to the largest per-slot write plan (power-of-
         # two bucket, mirroring the table-width buckets, so scan-length
         # compiles stay O(log ticks)): a drain tail of short-budget
@@ -684,32 +664,6 @@ class ServeEngine:
                             break
                     if retired:
                         break
-        return finished
-
-    def _dispatch_legacy(self, live: list[int]) -> int:
-        """PR 3 hot loop: single tick, full tables, host argmax."""
-        toks = jnp.asarray(self._last_tok, jnp.int32)[:, None]
-        lens = jnp.asarray(self._ctx_len, jnp.int32)
-        self.stats["max_table_width"] = self.pages_per_seq
-        with self._mesh_cm():
-            logits, self.pool.pools = self._decode1(
-                self.params, toks, self.pool.pools, self.tables.device(),
-                lens)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        self.stats["decode_steps"] += 1
-        self.stats["dispatches"] += 1
-        self.stats["host_syncs"] += 1
-        finished = 0
-        for slot in live:
-            req = self.active[slot]
-            self._ctx_len[slot] += 1   # last_tok's KV was just written
-            tok = int(nxt[slot])
-            self._last_tok[slot] = tok
-            self._hist[slot, self._ctx_len[slot]] = tok
-            self.stats["decode_tokens"] += 1
-            if self._emit(req, tok):
-                self._retire(slot)
-                finished += 1
         return finished
 
     def run_until_drained(self, max_ticks: int = 10_000) -> list[Request]:

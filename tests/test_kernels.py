@@ -6,8 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core import lcs_reference
-from repro.kernels.attention import (attention_ref, flash_attention,
-                                     flash_attention_pallas)
+from repro.kernels.attention import attention_ref, flash_attention_pallas
 from repro.kernels.lcs import lcs_pallas, lcs_tile_pallas, lcs_tile_ref
 from repro.kernels.matmul import matmul, matmul_pallas, matmul_ref
 
@@ -98,8 +97,10 @@ def test_attention_kernel_matches_model_layer():
     q = jax.random.normal(jax.random.PRNGKey(9), (b, s, hq, d))
     k = jax.random.normal(jax.random.PRNGKey(10), (b, s, hkv, d))
     v = jax.random.normal(jax.random.PRNGKey(11), (b, s, hkv, d))
-    got = flash_attention(q, k, v, causal=True, bq=32, bk=32,
-                          interpret=True)
+    # the kernel takes (B, H, S, D); the models use (B, S, H, D)
+    got = flash_attention_pallas(
+        *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True, bq=32,
+        bk=32, interpret=True).transpose(0, 2, 1, 3)
     want = model_attention(q, k, v, q_positions=jnp.arange(s),
                            k_positions=jnp.arange(s), causal=True,
                            q_chunk=16)

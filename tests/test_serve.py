@@ -29,8 +29,10 @@ import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.configs import get_arch
-from repro.kernels.attention import (paged_attention_ref,
-                                     paged_decode_attention)
+from repro.kernels.attention import (paged_attention,
+                                     paged_attention_ref,
+                                     paged_flash_decode_pallas,
+                                     paged_flash_prefill_pallas)
 from repro.models import init_params
 from repro.serve import Request, ServeEngine, paco_page_size, \
     reference_decode
@@ -463,6 +465,46 @@ def test_decode_ticks_matches_single_ticks(params, cfg):
                                       np.asarray(pools_b[name]))
 
 
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "deepseek-v2-236b"])
+def test_prefill_chunk_matches_decode_ticks(arch):
+    """Prefill attends through the same paged op as decode: a
+    page-aligned ``prefill_chunk`` over tokens T and teacher-forced
+    ``decode_step_paged`` ticks over the same T, from the same start on
+    the same pool, give the same logits (float32, highest precision)
+    and write the same pool pages.  Both cache families: dense GQA and
+    MLA latents."""
+    from repro.models import (decode_step_paged, paged_cache_leaf_specs,
+                              prefill_chunk)
+    from repro.serve import paging
+
+    cfg = get_arch(arch).reduced()
+    assert cfg.dtype == jnp.float32
+    params = init_params(cfg, KEY)
+    page, chunk = 4, 8
+    row = jnp.asarray([5, 2, 7, 0], jnp.int32)   # scattered, no null page
+    toks = jax.random.randint(jax.random.PRNGKey(5), (2 * chunk,), 1,
+                              cfg.vocab)
+    pools = paging.init_pool(paged_cache_leaf_specs(cfg, page), 8,
+                             page).pools
+    with jax.default_matmul_precision("highest"):
+        # the context before the chunk under test, shared by both paths
+        _, pools = prefill_chunk(params, cfg, toks[None, :chunk],
+                                 jnp.asarray(0, jnp.int32), pools, row)
+        start = jnp.asarray(chunk, jnp.int32)
+        want, want_pools = prefill_chunk(params, cfg, toks[None, chunk:],
+                                         start, dict(pools), row)
+        got, got_pools = [], dict(pools)
+        for t in range(chunk):
+            lg, got_pools = decode_step_paged(
+                params, cfg, toks[None, chunk + t, None], got_pools,
+                row[None], (start + t)[None])
+            got.append(lg[0])
+    np.testing.assert_allclose(np.stack(got), want, atol=1e-5, rtol=0)
+    for name in want_pools:
+        np.testing.assert_allclose(got_pools[name], want_pools[name],
+                                   atol=1e-5, rtol=0)
+
+
 def test_fused_eos_mid_block(params, cfg):
     """eos firing INSIDE a 4-tick block: the device flags must stop the
     slot at exactly the reference position (later block entries are
@@ -639,16 +681,18 @@ def test_topk_sampling_respects_flags(params, cfg):
 
 
 # ---------------------------------------------------------------------------
-# paged-attention kernel parity (jnp production path + Pallas interpret)
+# paged-attention parity: the one op per cache family (the served jnp
+# path) and the Pallas kernels (interpret mode) against the dense oracles
 # ---------------------------------------------------------------------------
 
 
 def test_paged_latent_decode_matches_dense_ref():
-    """MLA latent decode lowering (jnp gather path + Pallas interpret) ==
-    the dense concat-and-broadcast oracle, on a prime page pool with
-    mixed (including zero-page) lengths."""
-    from repro.kernels.attention import (paged_latent_attention_ref,
-                                         paged_latent_decode_attention)
+    """MLA latent decode (the latent op at W=1 + the Pallas latent decode
+    kernel in interpret mode) == the dense concat-and-broadcast oracle,
+    on a prime page pool with mixed (including zero-page) lengths."""
+    from repro.kernels.attention import (paged_latent_attention,
+                                         paged_latent_attention_ref,
+                                         paged_latent_decode_pallas)
 
     b, h, kv, rope, page, n_pages, pps = 3, 4, 16, 8, 4, 13, 4
     scale = 1.0 / np.sqrt(kv + rope)
@@ -660,13 +704,12 @@ def test_paged_latent_decode_matches_dense_ref():
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 16, 1], jnp.int32)
     ref = paged_latent_attention_ref(ql, qr, ck, kr, bt, lens, scale=scale)
-    out = paged_latent_decode_attention(ql, qr, ck, kr, bt, lens,
-                                        scale=scale)
+    # the decode query sits at position lens - 1 (its own entry written)
+    out = paged_latent_attention(ql, qr, ck, kr, bt, lens - 1, scale=scale)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_latent_decode_attention(ql, qr, ck, kr, bt, lens,
-                                        scale=scale, use_kernel=True,
-                                        interpret=True)
-    np.testing.assert_allclose(pal, ref, atol=2e-6)
+    pal = paged_latent_decode_pallas(ql[:, 0], qr[:, 0], ck, kr, bt, lens,
+                                     scale=scale, interpret=True)
+    np.testing.assert_allclose(pal[:, None], ref, atol=2e-6)
 
 @pytest.mark.parametrize("kw", [
     {}, {"window": 6}, {"logit_cap": 20.0},
@@ -681,16 +724,28 @@ def test_paged_decode_matches_dense_ref(kw):
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 16, 1], jnp.int32)
     ref = paged_attention_ref(q, kp, vp, bt, lens, **kw)
-    out = paged_decode_attention(q, kp, vp, bt, lens, **kw)
+    out = paged_attention(q, kp, vp, bt, lens - 1, **kw)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_decode_attention(q, kp, vp, bt, lens, use_kernel=True,
-                                 interpret=True, **kw)
-    np.testing.assert_allclose(pal, ref, atol=2e-6)
+    pal = paged_flash_decode_pallas(q[:, 0], kp, vp, bt, lens,
+                                    scale=d ** -0.5, interpret=True, **kw)
+    np.testing.assert_allclose(pal[:, None], ref, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
-# paged PREFILL kernel parity (jnp production path + Pallas interpret)
+# paged PREFILL parity: the op at B=1 over the slot's block row, and the
+# Pallas prefill kernel (interpret)
 # ---------------------------------------------------------------------------
+
+def _prefill_op(q, kp, vp, row, start, **kw):
+    """The paged op as prefill calls it: one slot, its row as a table."""
+    return paged_attention(q, kp, vp, row[None], start[None], **kw)
+
+
+def _prefill_pallas(q, kp, vp, row, start, **kw):
+    return paged_flash_prefill_pallas(q[0], kp, vp, row, start,
+                                      scale=q.shape[-1] ** -0.5,
+                                      interpret=True, **kw)[None]
+
 
 @pytest.mark.parametrize("kw", [
     {}, {"window": 5}, {"logit_cap": 20.0},
@@ -701,8 +756,7 @@ def test_paged_prefill_matches_dense_ref(kw):
     Pallas interpret) == the dense gathered-cache oracle, for a chunk at
     a nonzero start offset (past context in earlier pages, stale data in
     later ones — masked by the global causal rule)."""
-    from repro.kernels.attention import (paged_prefill_attention,
-                                         paged_prefill_ref)
+    from repro.kernels.attention import paged_prefill_ref
 
     hq, hkv, d, page, n_pages, c = 4, 2, 16, 4, 13, 8
     q = jax.random.normal(KEY, (1, c, hq, d))
@@ -711,10 +765,9 @@ def test_paged_prefill_matches_dense_ref(kw):
     row = jnp.asarray([2, 5, 7, 11], jnp.int32)
     start = jnp.asarray(8, jnp.int32)   # second chunk of the slot
     ref = paged_prefill_ref(q, kp, vp, row, start, **kw)
-    out = paged_prefill_attention(q, kp, vp, row, start, **kw)
+    out = _prefill_op(q, kp, vp, row, start, **kw)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_prefill_attention(q, kp, vp, row, start, use_kernel=True,
-                                  interpret=True, **kw)
+    pal = _prefill_pallas(q, kp, vp, row, start, **kw)
     np.testing.assert_allclose(pal, ref, atol=2e-6)
 
 
@@ -727,8 +780,7 @@ def test_paged_prefill_prime_geometry_fixed(page, pps, n_pages, c, start):
     """Non-hypothesis prime-geometry pins (these run even where the
     property-test shim skips): odd pages, prime pools, multi-page and
     single-page chunks, first and last chunk positions."""
-    from repro.kernels.attention import (paged_prefill_attention,
-                                         paged_prefill_ref)
+    from repro.kernels.attention import paged_prefill_ref
 
     hq, hkv, d = 4, 2, 8
     rng = np.random.RandomState(page * 100 + pps)
@@ -739,17 +791,18 @@ def test_paged_prefill_prime_geometry_fixed(page, pps, n_pages, c, start):
     vp = jax.random.normal(jax.random.PRNGKey(2), (n_pages, page, hkv, d))
     st = jnp.asarray(start, jnp.int32)
     ref = paged_prefill_ref(q, kp, vp, row, st)
-    out = paged_prefill_attention(q, kp, vp, row, st)
+    out = _prefill_op(q, kp, vp, row, st)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_prefill_attention(q, kp, vp, row, st, use_kernel=True,
-                                  interpret=True)
+    pal = _prefill_pallas(q, kp, vp, row, st)
     np.testing.assert_allclose(pal, ref, atol=2e-6)
 
 
 def test_paged_latent_prefill_matches_dense_ref():
-    """MLA latent prefill (decomposed-score jnp path + Pallas interpret)
-    == the dense concat-and-broadcast oracle, on a prime page pool."""
-    from repro.kernels.attention import (paged_latent_prefill_attention,
+    """MLA latent prefill (the latent op at B=1 + the Pallas latent
+    prefill kernel in interpret mode) == the dense concat-and-broadcast
+    oracle, on a prime page pool."""
+    from repro.kernels.attention import (paged_latent_attention,
+                                         paged_latent_prefill_pallas,
                                          paged_latent_prefill_ref)
 
     h, kv, rope, page, n_pages, c = 4, 16, 8, 4, 13, 8
@@ -763,13 +816,12 @@ def test_paged_latent_prefill_matches_dense_ref():
         st = jnp.asarray(start, jnp.int32)
         ref = paged_latent_prefill_ref(ql, qr, ck, kr, row, st,
                                        scale=scale)
-        out = paged_latent_prefill_attention(ql, qr, ck, kr, row, st,
-                                             scale=scale)
+        out = paged_latent_attention(ql, qr, ck, kr, row[None], st[None],
+                                     scale=scale)
         np.testing.assert_allclose(out, ref, atol=2e-6)
-        pal = paged_latent_prefill_attention(ql, qr, ck, kr, row, st,
-                                             scale=scale, use_kernel=True,
-                                             interpret=True)
-        np.testing.assert_allclose(pal, ref, atol=2e-6)
+        pal = paged_latent_prefill_pallas(ql[0], qr[0], ck, kr, row, st,
+                                          scale=scale, interpret=True)
+        np.testing.assert_allclose(pal[None], ref, atol=2e-6)
 
 
 @settings(max_examples=6, deadline=None)
@@ -787,8 +839,7 @@ def test_property_paged_prefill_prime_geometries(page, pps, extra_pages,
     geometries: jnp gather path AND the Pallas kernel (interpret) vs the
     dense oracle, with the chunk starting at an arbitrary chunk
     boundary (ISSUE 4 satellite)."""
-    from repro.kernels.attention import (paged_prefill_attention,
-                                         paged_prefill_ref)
+    from repro.kernels.attention import paged_prefill_ref
 
     hq, hkv, d = 4, 2, 8
     c = min(c_pages * page, pps * page)
@@ -804,10 +855,9 @@ def test_property_paged_prefill_prime_geometries(page, pps, extra_pages,
                            (n_pages, page, hkv, d))
     start = jnp.asarray(start_v, jnp.int32)
     ref = paged_prefill_ref(q, kp, vp, row, start)
-    out = paged_prefill_attention(q, kp, vp, row, start)
+    out = _prefill_op(q, kp, vp, row, start)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_prefill_attention(q, kp, vp, row, start, use_kernel=True,
-                                  interpret=True)
+    pal = _prefill_pallas(q, kp, vp, row, start)
     np.testing.assert_allclose(pal, ref, atol=2e-6)
 
 
@@ -909,7 +959,7 @@ def test_property_paged_attention_prime_pools(n_pages, lens):
     vp = jax.random.normal(jax.random.PRNGKey(4), (n_pages, page, hkv, d))
     lv = jnp.asarray(lens, jnp.int32)
     ref = paged_attention_ref(q, kp, vp, bt, lv)
-    out = paged_decode_attention(q, kp, vp, bt, lv)
+    out = paged_attention(q, kp, vp, bt, lv - 1)
     valid = np.asarray(lens) > 0   # zero-length rows are garbage-by-design
     np.testing.assert_allclose(np.asarray(out)[valid],
                                np.asarray(ref)[valid], atol=2e-6)

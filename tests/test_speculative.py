@@ -18,9 +18,11 @@ batched paged verification, exact greedy acceptance.
 (c) DRAFTER — the pure n-gram drafter is deterministic, matches a numpy
     oracle (hypothesis property), and only ever proposes tokens from
     the slot's own context.
-(d) KERNEL — paged_verify_attention (jnp + Pallas interpret) vs the
-    dense oracle, and the W=1 window pinned BITWISE against the decode
-    path (the equality the whole §8.8 parity argument rests on).
+(d) KERNEL — the paged op over a window (and the Pallas prefill
+    kernel vmapped over slots, interpret mode) vs the dense oracle, and
+    each row of a W-window pinned BITWISE against the one-token call at
+    its position (the equality the whole §8.8 parity argument rests
+    on).
 Plus the satellite guards: greedy-only speculation raises on sampled
 configs, and the engine's geometry asserts are real ValueErrors now.
 """
@@ -462,8 +464,6 @@ def test_speculate_rejects_sampled_configs(params, cfg):
     with pytest.raises(NotImplementedError,
                        match="(?i)rejection sampling"):
         ServeEngine(params, cfg, speculate=4, temperature=0.8)
-    with pytest.raises(ValueError, match="fused"):
-        ServeEngine(params, cfg, speculate=4, fused=False)
     with pytest.raises(ValueError, match="speculate"):
         ServeEngine(params, cfg, speculate=-1)
 
@@ -583,7 +583,8 @@ def test_property_draft_ngram(rows, draft_len, ngram):
     {"window": 3, "logit_cap": 5.0},
 ])
 def test_paged_verify_matches_dense_ref(kw):
-    from repro.kernels.attention import (paged_verify_attention,
+    from repro.kernels.attention import (paged_attention,
+                                         paged_flash_prefill_pallas,
                                          paged_verify_ref)
 
     b, w, hq, hkv, d, page, n_pages = 3, 4, 4, 2, 16, 4, 13
@@ -594,37 +595,41 @@ def test_paged_verify_matches_dense_ref(kw):
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 12, 0], jnp.int32)
     ref = paged_verify_ref(q, kp, vp, bt, lens, **kw)
-    out = paged_verify_attention(q, kp, vp, bt, lens, **kw)
+    out = paged_attention(q, kp, vp, bt, lens, **kw)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_verify_attention(q, kp, vp, bt, lens, use_kernel=True,
-                                 interpret=True, **kw)
+    # multi-token causal paged attention is the prefill kernel's job:
+    # one call per slot, with its own (start, block row)
+    pal = jax.vmap(lambda qb, row, st: paged_flash_prefill_pallas(
+        qb, kp, vp, row, st, scale=d ** -0.5, interpret=True, **kw))(
+        q, bt, lens)
     np.testing.assert_allclose(pal, ref, atol=2e-6)
 
 
 def test_paged_verify_w1_bitwise_decode():
-    """THE §8.8 parity anchor: a 1-token verify window computes
-    BIT-identical output to paged_decode_attention for the same token —
-    same gather, same einsum contraction, same mask values — so every
-    accepted speculative position reproduces the decode tick exactly."""
-    from repro.kernels.attention import (paged_decode_attention,
-                                         paged_verify_attention)
+    """THE §8.8 parity anchor: on the same pool, each row t of a
+    W-token window computes BIT-identical output to the one-token
+    (decode) call at position q_start + t — same gather, same einsum
+    contraction, same mask values — so every accepted speculative
+    position reproduces the decode tick exactly."""
+    from repro.kernels.attention import paged_attention
 
-    b, hq, hkv, d, page, n_pages = 3, 4, 2, 16, 4, 13
-    q = jax.random.normal(KEY, (b, 1, hq, d))
+    b, w, hq, hkv, d, page, n_pages = 3, 4, 4, 2, 16, 4, 13
+    q = jax.random.normal(KEY, (b, w, hq, d))
     kp = jax.random.normal(jax.random.PRNGKey(1), (n_pages, page, hkv, d))
     vp = jax.random.normal(jax.random.PRNGKey(2), (n_pages, page, hkv, d))
     bt = jnp.asarray(np.array([[0, 3, 5, 7], [1, 2, 4, 6],
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 12, 1], jnp.int32)
-    # verify's query at position lens attends keys <= lens; decode's
-    # lengths argument counts the current token as written: lens + 1
-    ver = paged_verify_attention(q, kp, vp, bt, lens)
-    dec = paged_decode_attention(q, kp, vp, bt, lens + 1)
-    np.testing.assert_array_equal(np.asarray(ver), np.asarray(dec))
+    ver = paged_attention(q, kp, vp, bt, lens)
+    for t in range(w):
+        dec = paged_attention(q[:, t:t + 1], kp, vp, bt, lens + t)
+        np.testing.assert_array_equal(np.asarray(ver[:, t:t + 1]),
+                                      np.asarray(dec))
 
 
 def test_paged_latent_verify_matches_dense_ref():
-    from repro.kernels.attention import (paged_latent_verify_attention,
+    from repro.kernels.attention import (paged_latent_attention,
+                                         paged_latent_prefill_pallas,
                                          paged_latent_verify_ref)
 
     b, w, h, kv, rope, page, n_pages = 3, 4, 4, 16, 8, 4, 13
@@ -637,33 +642,32 @@ def test_paged_latent_verify_matches_dense_ref():
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 12, 0], jnp.int32)
     ref = paged_latent_verify_ref(ql, qr, ck, kr, bt, lens, scale=scale)
-    out = paged_latent_verify_attention(ql, qr, ck, kr, bt, lens,
-                                        scale=scale)
+    out = paged_latent_attention(ql, qr, ck, kr, bt, lens, scale=scale)
     np.testing.assert_allclose(out, ref, atol=2e-6)
-    pal = paged_latent_verify_attention(ql, qr, ck, kr, bt, lens,
-                                        scale=scale, use_kernel=True,
-                                        interpret=True)
+    pal = jax.vmap(lambda a, r, row, st: paged_latent_prefill_pallas(
+        a, r, ck, kr, row, st, scale=scale, interpret=True))(
+        ql, qr, bt, lens)
     np.testing.assert_allclose(pal, ref, atol=2e-6)
 
 
 def test_paged_latent_verify_w1_bitwise_decode():
-    from repro.kernels.attention import (paged_latent_decode_attention,
-                                         paged_latent_verify_attention)
+    from repro.kernels.attention import paged_latent_attention
 
-    b, h, kv, rope, page, n_pages = 3, 4, 16, 8, 4, 13
+    b, w, h, kv, rope, page, n_pages = 3, 4, 4, 16, 8, 4, 13
     scale = 1.0 / np.sqrt(kv + rope)
-    ql = jax.random.normal(KEY, (b, 1, h, kv))
-    qr = jax.random.normal(jax.random.PRNGKey(9), (b, 1, h, rope))
+    ql = jax.random.normal(KEY, (b, w, h, kv))
+    qr = jax.random.normal(jax.random.PRNGKey(9), (b, w, h, rope))
     ck = jax.random.normal(jax.random.PRNGKey(1), (n_pages, page, kv))
     kr = jax.random.normal(jax.random.PRNGKey(2), (n_pages, page, rope))
     bt = jnp.asarray(np.array([[0, 3, 5, 7], [1, 2, 4, 6],
                                [8, 9, 10, 11]], np.int32))
     lens = jnp.asarray([5, 12, 1], jnp.int32)
-    ver = paged_latent_verify_attention(ql, qr, ck, kr, bt, lens,
-                                        scale=scale)
-    dec = paged_latent_decode_attention(ql, qr, ck, kr, bt, lens + 1,
-                                        scale=scale)
-    np.testing.assert_array_equal(np.asarray(ver), np.asarray(dec))
+    ver = paged_latent_attention(ql, qr, ck, kr, bt, lens, scale=scale)
+    for t in range(w):
+        dec = paged_latent_attention(ql[:, t:t + 1], qr[:, t:t + 1], ck, kr,
+                                     bt, lens + t, scale=scale)
+        np.testing.assert_array_equal(np.asarray(ver[:, t:t + 1]),
+                                      np.asarray(dec))
 
 
 # ---------------------------------------------------------------------------

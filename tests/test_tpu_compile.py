@@ -23,7 +23,6 @@ from repro.kernels.attention.attention import (paged_flash_decode_pallas,
                                                paged_flash_prefill_pallas,
                                                paged_latent_decode_pallas,
                                                paged_latent_prefill_pallas)
-from repro.kernels.attention.ops import paged_verify_attention
 from repro.models import init_params
 from repro.serve import ServeEngine, paging
 
@@ -132,9 +131,10 @@ def _kernel(name, one_chip):
                 *a, scale=scale), (shape(b, hq, d), kv, kv, tables, lens)),
             "gqa_prefill": (lambda *a: paged_flash_prefill_pallas(
                 *a, scale=scale), (shape(chunk, hq, d), kv, kv, row, start)),
-            "gqa_verify": (lambda *a: paged_verify_attention(
-                *a, use_kernel=True), (shape(b, 8, hq, d), kv, kv, tables,
-                                       lens)),
+            "gqa_verify": (lambda q, k, v, bt, st: jax.vmap(
+                lambda qb, row, s0: paged_flash_prefill_pallas(
+                    qb, k, v, row, s0, scale=scale))(q, bt, st),
+                (shape(b, 8, hq, d), kv, kv, tables, lens)),
         }[name]
     cfg = get_arch("deepseek-v2-236b")
     h, m = cfg.n_heads, cfg.mla
